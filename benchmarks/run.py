@@ -108,6 +108,9 @@ def _write_current(rows: dict[str, float], verdicts: dict[str, str],
 
 
 def main() -> None:
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     argv = list(sys.argv[1:])
     check = "--check" in argv
     if check:

@@ -18,6 +18,7 @@ sys.path.insert(0, "src")
 
 import numpy as np  # noqa: E402
 
+from repro.anomaly.incident import incident_columns  # noqa: E402
 from repro.core import (  # noqa: E402
     BigRootsAnalyzer,
     JAX_FEATURES,
@@ -230,43 +231,12 @@ def streaming_scale(hosts_per_step: int = 2048, window_steps: int = 8,
     return rows, csv
 
 
-def _incident_columns(n_hosts: int, seed: int = 0) -> dict:
-    """A fleet-incident step window: the Mantri λs threshold flags ~20% of
-    rows as stragglers (contended rack / hot shard storm) while only a
-    small attributable subset carries a real feature signal.  This is the
-    gate-dominated regime the fleet sweep batches: Eq. 5 algebra runs over
-    every straggler row, but emission stays small."""
-    rng = np.random.default_rng(seed)
-    dur = rng.lognormal(mean=0.0, sigma=0.18, size=n_hosts) * 10.0
-    slow = rng.choice(n_hosts, size=n_hosts // 5, replace=False)
-    dur[slow] *= 1.9
-    cpu = rng.uniform(0.1, 0.3, n_hosts)
-    cpu[slow[: n_hosts // 500]] = 0.95  # the attributable hot set (~0.2%)
-    return {
-        "task_ids": [f"h{i}/s0" for i in range(n_hosts)],
-        "nodes": [f"h{i % 512}" for i in range(n_hosts)],
-        "starts": np.zeros(n_hosts),
-        "ends": dur,
-        # Tight feature spreads: the 1.5× peer-mean gate rejects organic
-        # variation, so only the injected hot set emits causes.
-        "features": {
-            "cpu": cpu,
-            "disk": rng.uniform(0.15, 0.2, n_hosts),
-            "network": rng.uniform(5e5, 6e5, n_hosts),
-            "read_bytes": rng.uniform(0.95, 1.05, n_hosts) * 64e6,
-            "gc_time": rng.uniform(0, 0.05, n_hosts),
-            "data_load_time": rng.uniform(0, 0.4, n_hosts),
-            "h2d_time": rng.uniform(0, 0.1, n_hosts),
-        },
-    }
-
-
 def fleet_gates(n_windows: int = 8, rows: int = 16384, reps: int = 5):
     """Fleet sweep: batched Eq. 5 gate evaluation vs per-window analyze.
 
     ``n_windows`` live 16k-row stage windows (one per job/stage on the
     fleet) are diagnosed in the same incident tick (see
-    ``_incident_columns``):
+    ``incident_columns``):
 
     - ``fleet_sweep_numpy``: the pre-PR3 shape — loop
       ``analyze_stage(w)`` per window (numpy gates per window);
@@ -285,7 +255,7 @@ def fleet_gates(n_windows: int = 8, rows: int = 16384, reps: int = 5):
     an_np = BigRootsAnalyzer(JAX_FEATURES)
     windows = []
     for wi in range(n_windows):
-        cols = _incident_columns(rows, seed=100 + wi)
+        cols = incident_columns(rows, seed=100 + wi)
         w = SlidingStageWindow(f"s{wi}", JAX_FEATURES, max_rows=rows,
                                quantile=an_np.thresholds.quantile)
         w.add_rows(cols["task_ids"], cols["nodes"], cols["starts"],
@@ -315,16 +285,8 @@ def fleet_gates(n_windows: int = 8, rows: int = 16384, reps: int = 5):
                 f"stragglers={sum(len(sa.straggler_ids) for sa in res_np)}"))
     for backend in ("jax", "pallas"):
         an = BigRootsAnalyzer(JAX_FEATURES, backend=backend,
-                              backend_min_rows=0)
+                              backend_min_rows=0, kernel_interpret=True)
         us, res = timed(lambda: an.analyze_fleet(windows))
-        if an.backend != backend:
-            # jax missing → the analyzer degraded to numpy gates.  Emit
-            # under a _SKIPPED name so the gated row goes MISSING (loud
-            # check failure) instead of recording numpy timings under a
-            # jax/pallas label.
-            csv.append((f"scale/gates_fleet_{backend}_{tag}_SKIPPED", us,
-                        "backend degraded to numpy (no jax)"))
-            continue
         diff = sum(
             len(found_set(sa.root_causes) ^ want[sa.stage_id]) for sa in res
         )
@@ -350,12 +312,8 @@ def fleet_gates(n_windows: int = 8, rows: int = 16384, reps: int = 5):
         lambda: eval_gates_np(batch, an_np.thresholds.peer_mean))
     for backend in ("jax", "pallas"):
         an = BigRootsAnalyzer(JAX_FEATURES, backend=backend,
-                              backend_min_rows=0)
+                              backend_min_rows=0, kernel_interpret=True)
         us, out = timed(lambda: an._eval_gates_batch(batch))
-        if an.backend != backend:  # degraded to numpy — see fleet rows
-            csv.append((f"scale/gates_eval_{backend}_{tag}_SKIPPED", us,
-                        "backend degraded to numpy (no jax)"))
-            continue
         bits_equal = int(np.array_equal(out, oracle_out))
         note = ";interpret_mode_cpu" if backend == "pallas" else ""
         csv.append((f"scale/gates_eval_{backend}_{tag}", us,
@@ -394,7 +352,7 @@ def fleet_merge(n_hosts: int = 8, rows_per_host: int = 2048, reps: int = 5):
     host_cols = []
     host_windows = []
     for h in range(n_hosts):
-        cols = _incident_columns(rows_per_host, seed=300 + h)
+        cols = incident_columns(rows_per_host, seed=300 + h)
         cols["task_ids"] = [f"h{h}/t{i}" for i in range(rows_per_host)]
         cols["nodes"] = [f"host{h}-n{i % 64}" for i in range(rows_per_host)]
         w = SlidingStageWindow("s0", JAX_FEATURES, quantile=q)
@@ -470,7 +428,7 @@ def fleet_merge(n_hosts: int = 8, rows_per_host: int = 2048, reps: int = 5):
 def _host_stream_columns(host: int, rows: int, seed: int = 0) -> dict:
     """What one host's wire stream actually looks like over ``rows``
     consecutive steps — the workload StepDelta v2's delta compression is
-    built for, unlike ``_incident_columns`` whose i.i.d. random features
+    built for, unlike ``incident_columns`` whose i.i.d. random features
     are a worst case (random mantissas are incompressible losslessly).
     Hot columns are near-constant step to step: byte counters are exact
     integers, /proc-derived utilizations are quantized jiffy ratios, GC
@@ -519,7 +477,7 @@ def wire_transport(n_hosts: int = 8, rows_per_host: int = 2048,
       FleetAggregator, one fleet diagnosis step.  The derived column
       carries the honest size story: ``ratio`` is v1/v2 bytes on the
       per-host stream payloads (the acceptance bar is ≥2×), and
-      ``incident_ratio`` the same on ``_incident_columns`` payloads —
+      ``incident_ratio`` the same on ``incident_columns`` payloads —
       the adversarial i.i.d.-random case where lossless compression
       bottoms out near the mantissa entropy floor.
     - ``wire_v1_tick_8hosts``: the identical tick over v1 payloads (the
@@ -545,7 +503,7 @@ def wire_transport(n_hosts: int = 8, rows_per_host: int = 2048,
 
     inc1 = inc2 = 0
     for h in range(n_hosts):
-        cols = _incident_columns(rows_per_host, seed=300 + h)
+        cols = incident_columns(rows_per_host, seed=300 + h)
         cols["task_ids"] = [f"h{h}/t{i}" for i in range(rows_per_host)]
         cols["nodes"] = [f"host{h}-n{i % 64}" for i in range(rows_per_host)]
         inc1 += len(_stream_payload(cols, h, 1))
@@ -887,7 +845,7 @@ def policy_eval(n_hosts: int = 16384, steps: int = 64, reps: int = 5):
 
 def whatif_replay(n_hosts: int = 16384, reps: int = 5):
     """What-if counterfactual replay at fleet scale: one attribution tick
-    over a 16k-row incident window (see ``_incident_columns``).
+    over a 16k-row incident window (see ``incident_columns``).
 
     The replayer prices every cause the analyzer just emitted — packs the
     window into the [W, R, F] gate layout, rebases the implicated rows to
@@ -909,7 +867,7 @@ def whatif_replay(n_hosts: int = 16384, reps: int = 5):
     from repro.core.whatif import WhatIfReplayer
 
     an = BigRootsAnalyzer(JAX_FEATURES)
-    cols = _incident_columns(n_hosts, seed=42)
+    cols = incident_columns(n_hosts, seed=42)
     w = SlidingStageWindow("s0", JAX_FEATURES, max_rows=n_hosts,
                            quantile=an.thresholds.quantile)
     w.add_rows(cols["task_ids"], cols["nodes"], cols["starts"],

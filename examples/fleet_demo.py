@@ -58,6 +58,10 @@ v3 (``BRD3``) payload carrying a priced RootCause is pushed through a
 original bytes verbatim with the root re-emitting the cause's
 ``Attribution`` intact.
 
+Host and aggregator children run with ``JAX_PLATFORMS=cpu``: a JAX
+device belongs to one process, the diagnosing root, and the children
+only produce and forward bytes.
+
 Exits non-zero if the cause streams differ, the attributed payload does
 not survive the tree hop byte-identically, no dropout escalation
 surfaced (star mode), or rows were lost or duplicated through the
@@ -191,6 +195,12 @@ def agg_of(host_index: int, aggs: int, hosts: int) -> int:
     return host_index * aggs // hosts
 
 
+def child_env() -> dict:
+    """Environment of a host or aggregator child: off JAX devices, which
+    belong to the diagnosing parent."""
+    return {**os.environ, "JAX_PLATFORMS": "cpu"}
+
+
 def fresh_aggregator(lease: float | None) -> FleetAggregator:
     return FleetAggregator(
         JAX_FEATURES, BigRootsAnalyzer(JAX_FEATURES), lease=lease,
@@ -291,6 +301,7 @@ def run_parent(args) -> int:
              "--transport", args.transport,
              "--connect", connect_for[f"h{i}"],
              "--pace", str(args.pace)],
+            env=child_env(),
         )
     kill_target = (f"h{STRAGGLER_HOST_INDEX}"
                    if args.hosts > 1 and args.kill_after > 0 else None)
@@ -412,7 +423,8 @@ def run_tree_parent(args) -> int:
                 "--journal", os.path.join(workdir, f"agg{j}.journal"),
                 "--connect", root_addr, "--pace", str(args.pace)]
 
-    agg_procs = {j: subprocess.Popen(agg_cmd(j)) for j in range(args.aggs)}
+    agg_procs = {j: subprocess.Popen(agg_cmd(j), env=child_env())
+                 for j in range(args.aggs)}
     deadline = time.time() + args.timeout
     while (any(not os.path.exists(os.path.join(workdir, f"agg{j}.sock"))
                for j in range(args.aggs)) and time.time() < deadline):
@@ -427,6 +439,7 @@ def run_tree_parent(args) -> int:
              "--transport", "unix",
              "--connect", "unix:" + os.path.join(workdir, f"agg{j}.sock"),
              "--pace", str(args.pace)],
+            env=child_env(),
         )
 
     kill_agg = agg_of(STRAGGLER_HOST_INDEX, args.aggs, args.hosts)
@@ -486,7 +499,8 @@ def run_tree_parent(args) -> int:
             print(f"[tree] restarting agg{kill_agg} from its journal "
                   f"(survivors advanced "
                   f"{survivor_progress() - progress_base} deltas)")
-            agg_procs[kill_agg] = subprocess.Popen(agg_cmd(kill_agg))
+            agg_procs[kill_agg] = subprocess.Popen(agg_cmd(kill_agg),
+                                                   env=child_env())
             restarted = True
         hosts_done = all(p.poll() is not None for p in host_procs.values())
         if hosts_done and agg.rows_ingested >= expected_rows:

@@ -11,6 +11,11 @@ Faithful to the paper's designs:
 The paper launches 8 worker processes per AG; ``workers`` defaults to 8 and
 should be scaled down on small hosts.  Generators are context managers and
 are safe to kill (daemon processes, explicit terminate on stop).
+
+Workers are *spawned*, never forked: the process that starts them may
+hold a JAX device, and a device belongs to that one process.  A spawned
+worker inherits none of its runtime state or open device files, and the
+hogs never import JAX.
 """
 from __future__ import annotations
 
@@ -23,6 +28,7 @@ import time
 
 
 DEFAULT_WORKERS = 8
+_SPAWN = mp.get_context("spawn")
 
 
 def _cpu_hog(stop_evt, dump_dir: str, n: int = 1_000_000) -> None:
@@ -98,7 +104,7 @@ class _BaseGenerator:
     def __init__(self, workers: int = DEFAULT_WORKERS) -> None:
         self.workers = workers
         self._procs: list[mp.Process] = []
-        self._stop = mp.Event()
+        self._stop = _SPAWN.Event()
 
     def _targets(self) -> list[tuple]:
         raise NotImplementedError
@@ -106,7 +112,7 @@ class _BaseGenerator:
     def start(self) -> "_BaseGenerator":
         self._stop.clear()
         for target, args in self._targets():
-            p = mp.Process(target=target, args=args, daemon=True)
+            p = _SPAWN.Process(target=target, args=args, daemon=True)
             p.start()
             self._procs.append(p)
         return self
@@ -163,13 +169,15 @@ class NetworkAnomalyGenerator(_BaseGenerator):
         self._port: int | None = None
 
     def start(self):
-        q: mp.Queue = mp.Queue()
-        self._server = mp.Process(target=_net_server, args=(q,), daemon=True)
+        q = _SPAWN.Queue()
+        self._server = _SPAWN.Process(target=_net_server, args=(q,),
+                                      daemon=True)
         self._server.start()
         self._port = q.get(timeout=10.0)
         self._stop.clear()
         for _ in range(self.workers):
-            p = mp.Process(target=_net_hog, args=(self._stop, self._port), daemon=True)
+            p = _SPAWN.Process(target=_net_hog, args=(self._stop, self._port),
+                               daemon=True)
             p.start()
             self._procs.append(p)
         return self

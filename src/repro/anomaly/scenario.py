@@ -87,6 +87,7 @@ from ..core.analyzer import BigRootsAnalyzer, RootCause
 from ..core.features import JAX_FEATURES, FeatureKind
 from ..ft.policy import GuardrailConfig, PolicyEngine, RecordingActuator
 from ..serve.fleet import FleetAggregator, TreeAggregator
+from ..launch.cache import use_compile_cache
 from ..telemetry.events import StepTelemetry, WireFormatError
 
 __all__ = [
@@ -1222,6 +1223,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--budget", type=float, default=120.0,
                     help="per-scenario wall-time budget in seconds")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.list:
         for name, sc in SCENARIO_LIBRARY.items():

@@ -393,8 +393,10 @@ class BigRootsAnalyzer:
     ``repro.kernels.bigroots_gates`` (pure-jnp and Pallas respectively),
     fed by the ``repro.core.fleet`` packer.  Straggler detection, Eq. 6
     edge detection, Eq. 7 locality voting and RootCause emission always
-    run host-side; the backends are byte-identical on the equivalence
-    suite (float64 end to end under ``enable_x64``).
+    run host-side.  On the CPU the backends are byte-identical on the
+    equivalence suite (float64 end to end, Pallas in interpret mode); the
+    compiled TPU kernel runs in float32 and may flip only knife-edge rows
+    (:func:`repro.core.fleet.knife_edge`).
 
     Fleet-scale use: shard per-host telemetry, merge it launcher-side
     (``TraceStore.merge`` / ``SlidingStageWindow.merge`` /
@@ -413,7 +415,7 @@ class BigRootsAnalyzer:
         window_exact_quantiles: bool = False,
         backend: str = "numpy",
         backend_min_rows: int = 4096,
-        kernel_interpret: bool | None = None,
+        kernel_interpret: bool = False,
     ) -> None:
         if backend not in self.BACKENDS:
             raise ValueError(
@@ -431,15 +433,14 @@ class BigRootsAnalyzer:
         # docstring): "numpy" evaluates gates in-process; "jax"/"pallas"
         # route windows with at least ``backend_min_rows`` live rows (and
         # every ``analyze_fleet`` sweep) through
-        # ``repro.kernels.bigroots_gates``.  ``kernel_interpret`` forces
-        # Pallas interpret mode (None = auto: interpret off-TPU).  If jax
-        # is unavailable the accelerated backends degrade to numpy with a
-        # one-time warning rather than failing the serve loop.
+        # ``repro.kernels.bigroots_gates``.  ``kernel_interpret=True``
+        # runs the Pallas kernel in the interpreter (the CPU path); without
+        # it ``"pallas"`` needs a TPU and raises elsewhere.  A kernel that
+        # cannot import or compile raises: no backend stands in for another.
         self.backend = backend
         self.backend_min_rows = int(backend_min_rows)
-        self.kernel_interpret = kernel_interpret
+        self.kernel_interpret = bool(kernel_interpret)
         self._tl_cursor = None
-        self._kernel_warned = False
         self._pack_scratch = None  # reused FleetGateBatch (see pack_windows)
 
     # -- public API ----------------------------------------------------------
@@ -634,38 +635,20 @@ class BigRootsAnalyzer:
         return gate_q & (gate_inter | gate_intra), gate_inter, gate_intra
 
     def _eval_gates_batch(self, batch) -> np.ndarray:
-        """Run a packed FleetGateBatch through the selected gate backend,
-        degrading to the numpy oracle when jax is unavailable."""
+        """Run a packed FleetGateBatch through the selected gate backend."""
         th = self.thresholds
-        if self.backend in ("jax", "pallas"):
-            try:
-                from ..kernels.bigroots_gates import eval_gates
-            except Exception:
-                if not self._kernel_warned:
-                    self._kernel_warned = True
-                    import warnings
+        if self.backend == "numpy":
+            from .fleet import eval_gates_np
 
-                    warnings.warn(
-                        "repro.kernels.bigroots_gates unavailable (no jax?); "
-                        f"backend={self.backend!r} degrading to numpy gates",
-                        RuntimeWarning,
-                        stacklevel=3,
-                    )
-                # Flip permanently: subsequent steps take the in-process
-                # _gates_numpy path instead of re-packing a batch and
-                # re-attempting the import every call.
-                self.backend = "numpy"
-            else:
-                return eval_gates(
-                    batch.v, batch.peer_vsum, batch.inter_cnt,
-                    batch.intra_cnt, batch.rowmask, batch.vsum, batch.q,
-                    batch.numok, batch.floor,
-                    peer_mean=th.peer_mean, backend=self.backend,
-                    interpret=self.kernel_interpret,
-                )
-        from .fleet import eval_gates_np
+            return eval_gates_np(batch, th.peer_mean)
+        from ..kernels.bigroots_gates import eval_gates
 
-        return eval_gates_np(batch, th.peer_mean)
+        return eval_gates(
+            batch.v, batch.peer_vsum, batch.inter_cnt, batch.intra_cnt,
+            batch.rowmask, batch.vsum, batch.q, batch.numok, batch.floor,
+            peer_mean=th.peer_mean, backend=self.backend,
+            interpret=self.kernel_interpret,
+        )
 
     def analyze_fleet(self, windows) -> list[StageAnalysis]:
         """Fleet sweep: analyze many sliding windows with ONE batched gate

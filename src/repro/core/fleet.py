@@ -243,3 +243,52 @@ def eval_gates_np(batch: FleetGateBatch, peer_mean: float) -> np.ndarray:
         )
     gbits = gate_inter.astype(np.int8) + 2 * gate_intra.astype(np.int8)
     return np.where(fired, gbits, np.int8(0))
+
+
+def knife_edge(batch: FleetGateBatch, peer_mean: float) -> np.ndarray:
+    """Elements whose gate outcome float32 evaluation may flip → bool
+    ``[W, R, F]``.
+
+    The compiled gate kernel runs in float32 (Mosaic has no f64), while
+    :func:`eval_gates_np` is the float64 reference.  An element is on the
+    knife edge when its value ``v`` lies within float32 rounding of a
+    threshold it is compared against:
+
+    - λq and the TIME floor: ``|v - t| <= tol · (|v| + |t|)``;
+    - ``λp × inter`` with ``inter = (Σv - node Σv) / inter_cnt``: the f32
+      subtraction can be off by a rounding of each operand, so the scale
+      is ``|v| + λp · (|Σv| + |node Σv|) / inter_cnt``;
+    - ``λp × intra`` with ``intra = (node Σv - v) / intra_cnt``: likewise
+      with ``(|node Σv| + |v|) / intra_cnt``.
+
+    ``tol`` is four float32 epsilons — a few roundings of each operand
+    with room for the TPU's division — plus the smallest normal f32 as an
+    absolute term (TPUs flush denormals to zero).  Infinite thresholds
+    (the −inf floor of non-TIME columns, empty peer groups) are never
+    knife edges.  Every other element must agree bit for bit.
+    """
+    f32 = np.finfo(np.float32)
+    tol = 4.0 * float(f32.eps)
+    v = batch.v
+    av = np.abs(v)
+
+    def near(t: np.ndarray, scale: np.ndarray) -> np.ndarray:
+        with np.errstate(invalid="ignore"):
+            return np.isfinite(t) & (
+                np.abs(v - t) <= tol * scale + float(f32.tiny)
+            )
+
+    with np.errstate(invalid="ignore", divide="ignore"):
+        inter_cnt = np.where(batch.inter_cnt > 0, batch.inter_cnt, np.nan)
+        intra_cnt = np.where(batch.intra_cnt > 0, batch.intra_cnt, np.nan)
+        inter = peer_mean * (batch.vsum - batch.peer_vsum) / inter_cnt
+        intra = peer_mean * (batch.peer_vsum - v) / intra_cnt
+        inter_scale = av + peer_mean * (
+            np.abs(batch.vsum) + np.abs(batch.peer_vsum)) / inter_cnt
+        intra_scale = av + peer_mean * (np.abs(batch.peer_vsum) + av) / intra_cnt
+    return (
+        near(np.broadcast_to(batch.q, v.shape), av + np.abs(batch.q))
+        | near(inter, inter_scale)
+        | near(intra, intra_scale)
+        | near(np.broadcast_to(batch.floor, v.shape), av + np.abs(batch.floor))
+    )

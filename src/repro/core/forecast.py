@@ -35,7 +35,6 @@ many steps of warning each alarm threshold buys at what precision.
 """
 from __future__ import annotations
 
-import warnings
 from typing import Sequence
 
 import numpy as np
@@ -90,7 +89,6 @@ def train_forecaster(
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     sets = [episodes] if hasattr(episodes, "x") else list(episodes)
     x = np.concatenate([e.x for e in sets])
@@ -108,7 +106,7 @@ def train_forecaster(
     w = np.where(y > 0, pos_weight, 1.0)
 
     params = forecast_init(cfg, seed=seed)
-    with enable_x64():
+    with jax.enable_x64(True):
         xj = jnp.asarray(x)
         yj = jnp.asarray(y)
         wj = jnp.asarray(w)
@@ -237,10 +235,10 @@ class Forecaster:
     the training/evaluation view, used by the ROC harness and the
     equivalence tests; the tick path never pays its ``O(S·L·F)`` cost.
 
-    ``backend="jax"`` jits the portable forward under ``enable_x64``
-    (one cache entry per bucketed batch shape); if jax is unavailable it
-    falls back to numpy with a one-time :class:`RuntimeWarning` — same
-    arithmetic, same alarms, slower launch.
+    ``backend="jax"`` jits the portable forward under ``jax.enable_x64``
+    (one cache entry per bucketed batch shape; XLA emulates the f64 on a
+    TPU); ``backend="numpy"`` runs the same arithmetic in-process and is
+    the reference.  A jax import or compile failure raises.
     """
 
     def __init__(
@@ -270,7 +268,6 @@ class Forecaster:
         self._held: dict[str, int] = {}   # node -> tick the hold expires
         self._jit = None
         self._step_jit = None
-        self._warned = False
         # Carried recurrence state, keyed by (stage_id, node).
         H, N = config.hidden, config.state
         self._index: dict[tuple[str, str], int] = {}
@@ -313,10 +310,8 @@ class Forecaster:
         if batch.count == 0:
             return np.zeros(0, dtype=np.float64)
         if self.backend == "jax":
-            fn = self._jax_fn()
-            if fn is not None:
-                out = np.asarray(fn(self.params, batch.x, batch.mask))
-                return out[: batch.count]
+            out = np.asarray(self._jax_fn()(self.params, batch.x, batch.mask))
+            return out[: batch.count]
         out = forecast_score(self.params, batch.x[: batch.count],
                              mask=batch.mask[: batch.count], xp=np)
         return np.asarray(out, dtype=np.float64)
@@ -326,68 +321,43 @@ class Forecaster:
     ) -> tuple[np.ndarray, np.ndarray]:
         """One recurrence step over newest rows: ``(h_new, risks)``."""
         if self.backend == "jax":
-            fn = self._jax_step_fn()
-            if fn is not None:
-                h_new, sc = fn(self.params, rows, h, update)
-                return np.asarray(h_new), np.asarray(sc)
+            h_new, sc = self._jax_step_fn()(self.params, rows, h, update)
+            return np.asarray(h_new), np.asarray(sc)
         h_new, sc = forecast_step(self.params, rows, h, update=update, xp=np)
         return np.asarray(h_new), np.asarray(sc, dtype=np.float64)
 
-    def _import_jax(self):
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax.experimental import enable_x64
-        except Exception:
-            if not self._warned:
-                self._warned = True
-                warnings.warn(
-                    "jax unavailable; Forecaster falling back to the "
-                    "numpy backend (same scores, slower launch)",
-                    RuntimeWarning,
-                    stacklevel=4,
-                )
-            return None
-        return jax, jnp, enable_x64
-
     def _jax_fn(self):
         if self._jit is None:
-            mods = self._import_jax()
-            if mods is None:
-                self._jit = False
-                return None
-            jax, jnp, enable_x64 = mods
+            import jax
+            import jax.numpy as jnp
 
             inner = jax.jit(
                 lambda p, x, mk: forecast_score(p, x, mask=mk, xp=jnp)
             )
 
             def fn(p, x, mk):
-                with enable_x64():
+                with jax.enable_x64(True):
                     return inner(p, jnp.asarray(x), jnp.asarray(mk))
 
             self._jit = fn
-        return self._jit or None
+        return self._jit
 
     def _jax_step_fn(self):
         if self._step_jit is None:
-            mods = self._import_jax()
-            if mods is None:
-                self._step_jit = False
-                return None
-            jax, jnp, enable_x64 = mods
+            import jax
+            import jax.numpy as jnp
 
             inner = jax.jit(
                 lambda p, x, h, up: forecast_step(p, x, h, update=up, xp=jnp)
             )
 
             def fn(p, x, h, up):
-                with enable_x64():
+                with jax.enable_x64(True):
                     return inner(p, jnp.asarray(x), jnp.asarray(h),
                                  jnp.asarray(up))
 
             self._step_jit = fn
-        return self._step_jit or None
+        return self._step_jit
 
     # -- the tick hop ------------------------------------------------------
     def _align_state(self, batch: ForecastBatch):
@@ -442,10 +412,7 @@ class Forecaster:
         self._anchors = [self._anchors[i] for i in old]
 
     def step(self, windows) -> list[RootCause]:
-        """Advance per-node risk state one tick; emit candidate causes.
-
-        Never raises into the tick: the forecast hop is advisory, so any
-        scoring failure degrades to "no forecast this tick"."""
+        """Advance per-node risk state one tick; emit candidate causes."""
         self._tick += 1
         windows = [w for w in windows if w is not None]
         if not windows:

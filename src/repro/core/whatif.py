@@ -30,9 +30,9 @@ single top-2 reduction produces all per-row counterfactual makespans —
 removing row *i* leaves ``max(second_max, rebased_end_i)`` unless the
 max is tied, in which case removing one copy changes nothing.
 ``backend="jax"`` runs the reduction as one jitted jnp computation;
-``backend="numpy"`` (default) is the same arithmetic in-process, and a
-jax import failure degrades to numpy with a one-time RuntimeWarning,
-exactly like the analyzer's gate backends.
+``backend="numpy"`` (default) is the same arithmetic in-process and the
+reference.  A jax import or compile failure raises, as it does for the
+analyzer's gate backends.
 
 Invariants (pinned in ``tests/test_whatif.py``):
 
@@ -180,7 +180,6 @@ class WhatIfReplayer:
         self.row_bucket = max(int(row_bucket), 2)
         self.straggler_threshold = float(straggler_threshold)
         self._jit = None
-        self._warned = False
         # stage_id -> joint recovery of the last attribute() call: the
         # makespan reduction with *every* implicated row rebased at once
         # (what acting on the whole diagnosis would buy — per-cause
@@ -218,23 +217,9 @@ class WhatIfReplayer:
     def _run(self, ends, rebased, mask):
         if self.backend == "jax":
             if self._jit is None:
-                try:
-                    self._jit = _make_replay_jnp()
-                except Exception:
-                    if not self._warned:
-                        self._warned = True
-                        import warnings
-
-                        warnings.warn(
-                            "jax unavailable for the what-if replay; "
-                            "backend='jax' degrading to numpy",
-                            RuntimeWarning,
-                            stacklevel=3,
-                        )
-                    self.backend = "numpy"
-            if self._jit is not None:
-                t0, rec = self._jit(ends, rebased, mask)
-                return np.asarray(t0), np.asarray(rec)
+                self._jit = _make_replay_jnp()
+            t0, rec = self._jit(ends, rebased, mask)
+            return np.asarray(t0), np.asarray(rec)
         return _replay_np(ends, rebased, mask)
 
     # -- the replay ---------------------------------------------------------

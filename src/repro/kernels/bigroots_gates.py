@@ -31,16 +31,23 @@ Output: ``gbits [W, R, F]`` int8 — 0 where no gate fired; else bit 0 set
 when the inter-node observation fired and bit 1 for intra-node, matching
 the analyzer's peer-group emission table.
 
-Exactness: gate math runs in the input dtype.  The equivalence suite (and
-the analyzer's ``backend="jax"|"pallas"`` dispatch) runs under
-``jax.experimental.enable_x64`` so float64 comparisons are bit-identical
-to the numpy reference path; on a real TPU the same kernel compiles in
-float32 (Mosaic has no f64) — knife-edge λq rows may then differ, exactly
-like the documented P² sketch tolerance.  Division by an empty peer
-group's zero count produces NaN/±inf that the explicit ``cnt > 0`` masks
-neutralize, mirroring the numpy path's ``isnan`` guards.
+Precision: gate math runs in the operand dtype.  Interpret mode (the
+CPU path) and the jnp twin run under ``jax.enable_x64`` in float64 (on a
+TPU, XLA emulates the twin's f64), so on the CPU their gbits are
+bit-identical to the numpy oracle.  The compiled kernel runs in float32,
+because Mosaic has no f64: :func:`eval_gates` casts the batch for it.  A
+row whose value lies within float32 rounding of a threshold it is
+compared against (λq, ``λp × inter``, ``λp × intra`` or the TIME floor)
+may then fire differently from the f64 oracle;
+:func:`repro.core.fleet.knife_edge` marks exactly those elements, and
+the tests and ``chip_smoke.py`` hold every other element to bit
+identity.  Division by an empty peer group's zero count produces
+NaN/±inf that the explicit ``cnt > 0`` masks neutralize, mirroring the
+numpy path's ``isnan`` guards.
 
-Validated in interpret mode on CPU (CI); compiled by Mosaic on TPU.
+The gate bits are summed in int32 and cast to int8 only at the store:
+Mosaic has no i8 vector arithmetic.  Off-TPU the kernel runs only in
+interpret mode, and only when the caller asks for it (``interpret=True``).
 """
 from __future__ import annotations
 
@@ -48,16 +55,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 from jax.experimental import pallas as pl
 
 import numpy as np
-
-def _default_interpret() -> bool:
-    """Interpret off-TPU.  Resolved lazily at the first eval_gates call —
-    probing the backend at import time would initialize XLA for every
-    importer of repro.kernels, even ones that never evaluate a gate."""
-    return jax.default_backend() != "tpu"
 
 
 def _gates_kernel(v_ref, pv_ref, icnt_ref, acnt_ref, m_ref, vs_ref, q_ref,
@@ -85,8 +85,8 @@ def _gates_kernel(v_ref, pv_ref, icnt_ref, acnt_ref, m_ref, vs_ref, q_ref,
         & (numok > 0.0)                 # NUMERICAL stage-mean ≤ 0 guard
         & (v > floor)                   # TIME significance floor
     )
-    gbits = gate_inter.astype(jnp.int8) + 2 * gate_intra.astype(jnp.int8)
-    o_ref[0] = jnp.where(fired, gbits, jnp.int8(0))
+    gbits = gate_inter.astype(jnp.int32) + 2 * gate_intra.astype(jnp.int32)
+    o_ref[0] = jnp.where(fired, gbits, 0).astype(jnp.int8)
 
 
 @functools.partial(
@@ -151,25 +151,35 @@ def eval_gates(
     peer_mean: float,
     backend: str = "pallas",
     block_r: int = 256,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> np.ndarray:
     """Evaluate the Eq. 5 gate pipeline for a packed fleet batch.
 
-    ``backend="pallas"`` launches the kernel (interpret mode off-TPU by
-    default); ``backend="jax"`` runs the jit'd pure-jnp reference.  For
-    BOTH backends rows are zero-padded to a ``block_r`` multiple: the
-    kernel grid needs it, and the jnp path needs the shape *bucketing* —
-    an always-on loop sees a drifting straggler count every step, and
-    without padding each distinct count would retrace and recompile the
-    jit cache (tens of ms) instead of hitting one entry per bucket.
-    Padding is masked by construction (``rowmask`` padding is 0).  Runs
-    under ``enable_x64`` so float64 batches stay float64 end to end.
-    Returns ``gbits`` as a numpy int8 array of the unpadded shape.
+    ``backend="pallas"`` launches the kernel: compiled by Mosaic in
+    float32 on a TPU, or in the Pallas interpreter in float64 when
+    ``interpret=True``.  Off-TPU without ``interpret=True`` it raises
+    rather than run anything in the chip's place.  ``backend="jax"`` runs
+    the jit'd pure-jnp twin in float64 on any backend.  For BOTH backends
+    rows are zero-padded to a ``block_r`` multiple: the kernel grid needs
+    it, and the jnp path needs the shape *bucketing* — an always-on loop
+    sees a drifting straggler count every step, and without padding each
+    distinct count would retrace and recompile the jit cache (tens of ms)
+    instead of hitting one entry per bucket.  ``block_r`` is rounded up
+    to a multiple of 32, the sublane tile of the int8 output.  Padding is
+    masked by construction (``rowmask`` padding is 0).  Returns ``gbits``
+    as a numpy int8 array of the unpadded shape.
     """
     if backend not in ("jax", "pallas"):
         raise ValueError(f"unknown gate backend: {backend!r}")
+    compiled = backend == "pallas" and not interpret
+    if compiled and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "the Pallas gate kernel compiles for TPU only, and JAX's "
+            f"backend here is {jax.default_backend()!r}; pass interpret=True "
+            "to run it in the Pallas interpreter"
+        )
     W, R, F = v.shape
-    block_r = max(8, min(int(block_r), _round_up(R, 8)))
+    block_r = _round_up(max(1, min(int(block_r), R)), 32)
     R_pad = _round_up(R, block_r)
     if R_pad != R:
         pad = ((0, 0), (0, R_pad - R), (0, 0))
@@ -180,19 +190,17 @@ def eval_gates(
         inter_cnt = np.pad(inter_cnt, pad, constant_values=1.0)
         intra_cnt = np.pad(intra_cnt, pad, constant_values=1.0)
         rowmask = np.pad(rowmask, pad)
-    with enable_x64():
-        args = (
-            jnp.asarray(v), jnp.asarray(peer_vsum), jnp.asarray(inter_cnt),
-            jnp.asarray(intra_cnt), jnp.asarray(rowmask), jnp.asarray(vsum),
-            jnp.asarray(q), jnp.asarray(numok), jnp.asarray(floor),
-        )
+    dtype = np.float32 if compiled else np.float64
+    with jax.enable_x64(not compiled):
+        args = [jnp.asarray(a, dtype=dtype) for a in (
+            v, peer_vsum, inter_cnt, intra_cnt, rowmask, vsum, q, numok,
+            floor)]
         if backend == "jax":
             out = _gates_jnp(*args, peer_mean=float(peer_mean))
         else:
             out = _gates_pallas(
                 *args, peer_mean=float(peer_mean), block_r=block_r,
-                interpret=(_default_interpret() if interpret is None
-                           else bool(interpret)),
+                interpret=bool(interpret),
             )
         return np.asarray(out)[:, :R]
 

@@ -1,9 +1,9 @@
 """Public jit'd wrappers over the Pallas kernels.
 
 These are the entry points models call when ``cfg.attention_impl="pallas"``
-etc.  On this container (CPU) kernels run with ``interpret=True``; on a real
-TPU the same call sites compile the Mosaic kernels.  `INTERPRET` flips the
-default per-platform.
+etc.  Off-TPU the kernels run with ``interpret=True``; on a TPU the same
+call sites compile the Mosaic kernels.  :func:`_interpret` picks the
+default per platform when the wrapper is called, never at import.
 """
 from __future__ import annotations
 
@@ -15,7 +15,9 @@ from .flash_attention import flash_attention
 from .moe_gmm import grouped_matmul
 from .ssd_scan import ssd_intra_chunk
 
-INTERPRET = jax.default_backend() != "tpu"
+def _interpret(interpret: bool | None) -> bool:
+    """``interpret`` if given, else interpret off-TPU."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
 # ---------------------------------------------------------------------------
@@ -32,7 +34,7 @@ def mha_flash(q, k, v, *, causal=True, block_q=128, block_k=128,
     v2 = v.transpose(0, 2, 1, 3).reshape(B * KV, v.shape[1], D)
     out = flash_attention(
         q2, k2, v2, causal=causal, block_q=block_q, block_k=block_k,
-        n_rep=n_rep, interpret=INTERPRET if interpret is None else interpret,
+        n_rep=n_rep, interpret=_interpret(interpret),
     )
     return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
 
@@ -48,7 +50,7 @@ def mha_decode(q, k_cache, v_cache, cache_len, *, block_k=512,
     v2 = v_cache.transpose(0, 2, 1, 3).reshape(B * KV, v_cache.shape[1], D)
     out = decode_attention(
         q2, k2, v2, cache_len, block_k=block_k, n_rep=n_rep,
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=_interpret(interpret),
     )
     return out.reshape(B, 1, H, D)
 
@@ -66,7 +68,7 @@ def ssd_chunked_pallas(x, dt, A, Bm, Cm, chunk: int, h0=None,
     Q = min(chunk, S)
     assert S % Q == 0
     Nc = S // Q
-    interp = INTERPRET if interpret is None else interpret
+    interp = _interpret(interpret)
 
     # [B,S,H,*] → [B,H,Nc,Q,*]
     xc = x.reshape(Bb, Nc, Q, H, P).transpose(0, 3, 1, 2, 4)
@@ -112,7 +114,7 @@ def moe_gmm_ffn(xs, group_sizes, w_gate, w_up, w_down, *, capacity_tile=128,
     """xs [T, d] tokens sorted by expert; group_sizes [E].
     Returns [T, d] expert-FFN outputs (same order).  Pads each group to the
     capacity tile, runs three grouped matmuls, then unpads."""
-    interp = INTERPRET if interpret is None else interpret
+    interp = _interpret(interpret)
     T, d = xs.shape
     E = w_gate.shape[0]
     cap = max(capacity_tile, ((T + E - 1) // E + capacity_tile - 1)

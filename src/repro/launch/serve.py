@@ -21,6 +21,7 @@ from ..serve.engine import Request, ServeEngine
 from ..telemetry.events import StepTelemetry
 from ..telemetry.sampler import SystemSampler
 from ..telemetry.timeline import ResourceTimeline
+from .cache import use_compile_cache
 
 
 def main() -> None:
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

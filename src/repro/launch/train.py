@@ -61,6 +61,7 @@ from ..telemetry.events import GcTimer, StepTelemetry
 from ..telemetry.transport import DeltaServer
 from ..telemetry.sampler import SystemSampler
 from ..telemetry.timeline import ResourceTimeline
+from .cache import use_compile_cache
 from ..train.optimizer import AdamWConfig
 from ..train.step import init_state, make_train_step
 
@@ -569,6 +570,7 @@ def run(args) -> dict:
 
 def main() -> None:
     args = build_argparser().parse_args()
+    use_compile_cache()
     out = run(args)
     print(out["report"])
     print(json.dumps({k: v for k, v in out.items() if k != "report"},

@@ -19,6 +19,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .config import ModelConfig
 from .layers import (
@@ -136,7 +137,12 @@ def embed_inputs(
 ) -> jax.Array:
     """Token embedding; modality frontends prepend precomputed embeddings."""
     cdt = jnp.dtype(cfg.dtype)
-    x = params["embed"].astype(cdt)[tokens]
+    # The rows follow the tokens' sharding: with explicit mesh axes a
+    # vocab-sharded table and batch-sharded tokens leave the gather's
+    # output sharding ambiguous, so it is named here.
+    tok = jax.typeof(tokens).sharding
+    x = params["embed"].astype(cdt).at[tokens].get(
+        out_sharding=tok.update(spec=P(*tok.spec, None)))
     if embeds is not None:
         x = jnp.concatenate([embeds.astype(cdt), x], axis=1)
     return x

@@ -22,7 +22,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 # The enclosing launcher publishes the concrete mesh here before tracing
@@ -66,11 +65,11 @@ def ep_moe_apply(p, x, cfg, capacity_factor: float = 1.25):
     w_specs["norm_scale"] = P(None)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(w_specs, P(dp, "model", None)),
         out_specs=(P(dp, "model", None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def block(pw, x_blk):
         b_loc, s_loc, _ = x_blk.shape

@@ -18,7 +18,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(
@@ -37,11 +36,11 @@ def pipeline_apply(
     param_specs = jax.tree.map(lambda _: P(axis), stage_params)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(param_specs, P(axis)),   # microbatches start on stage 0? —
         out_specs=P(axis),                 # see gather/scatter note below
-        check_rep=False,
+        check_vma=False,
     )
     def run(my_params, x_shard):
         # Each stage holds an equal slice of the microbatch dim; gather all

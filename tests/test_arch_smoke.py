@@ -70,19 +70,7 @@ class TestArchSmoke:
             p2 = jax.tree.map(lambda w, g: w - 1e-3 * g, p, grads)
             return loss, p2
 
-        try:
-            loss, params2 = step(params)
-        except NotImplementedError as e:
-            # Per-arch, not blanket: archs whose forward skips the barrier
-            # (enc-dec) still differentiate on old jax builds and must
-            # keep running; see conftest.grad_through_barrier_supported.
-            if "optimization_barrier" in str(e):
-                pytest.skip(
-                    "this jax build lacks the differentiation rule for "
-                    f"optimization_barrier ({arch} train-step gradient "
-                    "unavailable; forward/decode paths still covered)"
-                )
-            raise
+        loss, params2 = step(params)
         assert bool(jnp.isfinite(loss))
         # gradients actually changed the parameters
         changed = jax.tree.map(

@@ -248,14 +248,13 @@ class TestForecastCell:
     def test_jax_and_numpy_agree_to_ulp(self):
         jax = pytest.importorskip("jax")
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         cfg = self._cfg()
         params = forecast_init(cfg, seed=9)
         rng = np.random.default_rng(10)
         x = rng.lognormal(0, 0.5, (13, cfg.length, cfg.features))
         ref = forecast_score(params, x, xp=np)
-        with enable_x64():
+        with jax.enable_x64(True):
             fn = jax.jit(lambda p, x: forecast_score(p, x, xp=jnp))
             got = np.asarray(fn(
                 {k: jnp.asarray(v) for k, v in params.items()},

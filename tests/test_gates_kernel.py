@@ -2,9 +2,11 @@
 
 The jax and Pallas gate backends (``repro.kernels.bigroots_gates``, fed by
 the ``repro.core.fleet`` packer) must produce *byte-identical* RootCause
-sets to the numpy path — gates are float64 comparisons end to end (the
-kernel runs under ``enable_x64``; Pallas in interpret mode on CPU), so
-there is no tolerance to hide behind.  Covers the randomized analyzer
+sets to the numpy path on the CPU — gates are float64 comparisons end to
+end (the kernel runs under ``jax.enable_x64``; Pallas in interpret mode,
+asked for explicitly), so there is no tolerance to hide behind.  The
+compiled TPU kernel runs in float32; its contract — only knife-edge
+elements may flip — is pinned here by casting batches to float32.  Covers the randomized analyzer
 path, the corner cases (empty inter/intra peer groups, NaN values,
 stage-mean ≤ 0 numerical columns, TIME floor), padded-row masking in the
 fleet batch, and ``analyze_fleet`` ≡ per-window analysis.
@@ -27,8 +29,12 @@ from repro.core import (  # noqa: E402
     found_set,
     pack_windows,
 )
-from repro.core.fleet import FleetGateBatch, column_floor  # noqa: E402
-from repro.kernels.bigroots_gates import eval_gates  # noqa: E402
+from repro.core.fleet import (  # noqa: E402
+    FleetGateBatch,
+    column_floor,
+    knife_edge,
+)
+from repro.kernels.bigroots_gates import _gates_pallas, eval_gates  # noqa: E402
 from repro.telemetry import ResourceTimeline  # noqa: E402
 
 METRICS = ("cpu", "disk", "network")
@@ -99,6 +105,7 @@ def analyzers(th=BigRootsThresholds(), timelines=None, exact=True):
     mk = lambda backend: BigRootsAnalyzer(  # noqa: E731
         SPARK_FEATURES, th, timelines=timelines,
         window_exact_quantiles=exact, backend=backend, backend_min_rows=0,
+        kernel_interpret=True,
     )
     return mk("numpy"), mk("jax"), mk("pallas")
 
@@ -107,37 +114,38 @@ def causes_sorted(sa):
     return sorted(sa.root_causes, key=lambda c: (c.task_id, c.feature))
 
 
+def random_batch(rng, W=None, R=None, F=None):
+    W = W or int(rng.integers(1, 5))
+    R = R or int(rng.integers(1, 40))
+    F = F or int(rng.integers(1, 15))
+    counts = rng.integers(0, R + 1, size=W)
+    v = rng.normal(1.0, 2.0, (W, R, F))
+    peer_vsum = rng.normal(2.0, 4.0, (W, R, F))
+    inter_cnt = rng.integers(0, 6, (W, R, 1)).astype(np.float64)
+    intra_cnt = rng.integers(0, 6, (W, R, 1)).astype(np.float64)
+    rowmask = np.zeros((W, R, 1))
+    for i, c in enumerate(counts):
+        rowmask[i, :c, 0] = 1.0
+    vsum = rng.normal(0.0, 8.0, (W, 1, F))
+    q = rng.normal(0.5, 1.0, (W, 1, F))
+    numok = rng.choice([0.0, 1.0], (W, 1, F))
+    floor = np.where(rng.random((1, 1, F)) < 0.3, 0.2, -np.inf)
+    return FleetGateBatch(v, peer_vsum, inter_cnt, intra_cnt, rowmask,
+                          vsum, q, numok, floor, counts)
+
+
 class TestRawBatchEquivalence:
     """Kernel vs jnp vs numpy oracle on raw packed batches (no analyzer)."""
-
-    def _random_batch(self, rng, W=None, R=None, F=None):
-        W = W or int(rng.integers(1, 5))
-        R = R or int(rng.integers(1, 40))
-        F = F or int(rng.integers(1, 15))
-        counts = rng.integers(0, R + 1, size=W)
-        v = rng.normal(1.0, 2.0, (W, R, F))
-        peer_vsum = rng.normal(2.0, 4.0, (W, R, F))
-        inter_cnt = rng.integers(0, 6, (W, R, 1)).astype(np.float64)
-        intra_cnt = rng.integers(0, 6, (W, R, 1)).astype(np.float64)
-        rowmask = np.zeros((W, R, 1))
-        for i, c in enumerate(counts):
-            rowmask[i, :c, 0] = 1.0
-        vsum = rng.normal(0.0, 8.0, (W, 1, F))
-        q = rng.normal(0.5, 1.0, (W, 1, F))
-        numok = rng.choice([0.0, 1.0], (W, 1, F))
-        floor = np.where(rng.random((1, 1, F)) < 0.3, 0.2, -np.inf)
-        return FleetGateBatch(v, peer_vsum, inter_cnt, intra_cnt, rowmask,
-                              vsum, q, numok, floor, counts)
 
     @pytest.mark.parametrize("backend", ["jax", "pallas"])
     def test_randomized_bit_identical(self, backend):
         for seed in range(20):
             rng = np.random.default_rng(seed)
-            b = self._random_batch(rng)
+            b = random_batch(rng)
             want = eval_gates_np(b, peer_mean=1.5)
             got = eval_gates(b.v, b.peer_vsum, b.inter_cnt, b.intra_cnt,
                              b.rowmask, b.vsum, b.q, b.numok, b.floor,
-                             peer_mean=1.5, backend=backend)
+                             peer_mean=1.5, backend=backend, interpret=True)
             np.testing.assert_array_equal(got, want, err_msg=f"seed={seed}")
 
     @pytest.mark.parametrize("backend", ["jax", "pallas"])
@@ -145,7 +153,7 @@ class TestRawBatchEquivalence:
         """NaN gate-space values and empty peer groups (cnt 0 → 0/0 NaN
         peer means) must produce gbits 0 on every backend."""
         rng = np.random.default_rng(99)
-        b = self._random_batch(rng, W=2, R=16, F=6)
+        b = random_batch(rng, W=2, R=16, F=6)
         b.v[0, :4] = np.nan
         b.inter_cnt[:, ::2] = 0.0
         b.intra_cnt[:, 1::2] = 0.0
@@ -155,7 +163,7 @@ class TestRawBatchEquivalence:
         want = eval_gates_np(b, peer_mean=1.5)
         got = eval_gates(b.v, b.peer_vsum, b.inter_cnt, b.intra_cnt,
                          b.rowmask, b.vsum, b.q, b.numok, b.floor,
-                         peer_mean=1.5, backend=backend)
+                         peer_mean=1.5, backend=backend, interpret=True)
         np.testing.assert_array_equal(got, want)
         assert (got[0, :4] == 0).all()                       # NaN rows dark
         assert (got[:, ::2] & 1).sum() == 0                  # no inter fires
@@ -182,7 +190,7 @@ class TestRawBatchEquivalence:
                            vsum, q, numok, floor, counts)
         got = eval_gates(b.v, b.peer_vsum, b.inter_cnt, b.intra_cnt,
                          b.rowmask, b.vsum, b.q, b.numok, b.floor,
-                         peer_mean=1.5, backend=backend)
+                         peer_mean=1.5, backend=backend, interpret=True)
         np.testing.assert_array_equal(got, eval_gates_np(b, peer_mean=1.5))
         for i, c in enumerate(counts):
             assert (got[i, :c] > 0).all()    # real rows fire (by construction)
@@ -192,25 +200,97 @@ class TestRawBatchEquivalence:
         """Different block_r tilings of the same batch agree (the grid
         decomposition is an implementation detail)."""
         rng = np.random.default_rng(7)
-        b = self._random_batch(rng, W=2, R=70, F=9)
+        b = random_batch(rng, W=2, R=70, F=9)
         outs = [
             eval_gates(b.v, b.peer_vsum, b.inter_cnt, b.intra_cnt, b.rowmask,
                        b.vsum, b.q, b.numok, b.floor, peer_mean=1.5,
-                       backend="pallas", block_r=br)
-            for br in (8, 16, 256)
+                       backend="pallas", block_r=br, interpret=True)
+            for br in (32, 64, 256)
         ]
         np.testing.assert_array_equal(outs[0], outs[1])
         np.testing.assert_array_equal(outs[0], outs[2])
 
     def test_unknown_backend_raises(self):
         rng = np.random.default_rng(1)
-        b = self._random_batch(rng, W=1, R=4, F=2)
+        b = random_batch(rng, W=1, R=4, F=2)
         with pytest.raises(ValueError, match="unknown gate backend"):
             eval_gates(b.v, b.peer_vsum, b.inter_cnt, b.intra_cnt, b.rowmask,
                        b.vsum, b.q, b.numok, b.floor, peer_mean=1.5,
                        backend="tpuv9")
         with pytest.raises(ValueError, match="unknown backend"):
             BigRootsAnalyzer(SPARK_FEATURES, backend="cuda")
+
+
+def gates_f32_interpreted(b, peer_mean):
+    """The compiled kernel's float32 arithmetic, run in the interpreter."""
+    args = [jax.numpy.asarray(a, dtype=np.float32) for a in (
+        b.v, b.peer_vsum, b.inter_cnt, b.intra_cnt, b.rowmask, b.vsum, b.q,
+        b.numok, b.floor)]
+    return np.asarray(_gates_pallas(*args, peer_mean=peer_mean,
+                                    block_r=b.v.shape[1], interpret=True))
+
+
+def plant_knife_edges(b, rng, peer_mean, frac=0.3):
+    """Move a fraction of values a billionth above λq or above λp×inter:
+    float64 sees them above the threshold, float32 rounds onto it."""
+    q = np.broadcast_to(b.q, b.v.shape)
+    with np.errstate(invalid="ignore", divide="ignore"):
+        inter = peer_mean * (b.vsum - b.peer_vsum) / b.inter_cnt
+    pick = rng.random(b.v.shape) < frac
+    on_q = pick & (rng.random(b.v.shape) < 0.5)
+    on_inter = pick & ~on_q & np.isfinite(inter) & (b.inter_cnt > 0)
+    b.v[on_q] = q[on_q] + 1e-9 * np.abs(q[on_q])
+    b.v[on_inter] = inter[on_inter] + 1e-9 * np.abs(inter[on_inter])
+
+
+class TestFloat32Contract:
+    """On a TPU the kernel runs in float32 (Mosaic has no f64): only
+    knife-edge elements (``repro.core.fleet.knife_edge``) may differ from
+    the float64 oracle, and ``backend="pallas"`` never quietly becomes
+    the interpreter."""
+
+    def test_f32_flips_are_knife_edges(self):
+        flips = 0
+        for seed in range(10):
+            rng = np.random.default_rng(300 + seed)
+            b = random_batch(rng, W=3, R=64, F=14)
+            b.rowmask[:] = 1.0
+            plant_knife_edges(b, rng, peer_mean=1.5)
+            diff = gates_f32_interpreted(b, 1.5) != eval_gates_np(b, 1.5)
+            off_edge = diff & ~knife_edge(b, 1.5)
+            assert not off_edge.any(), f"seed={seed}: {np.argwhere(off_edge)}"
+            flips += int(diff.sum())
+        assert flips > 0  # the planted knife edges did flip
+
+    def test_knife_edge_is_narrow(self):
+        """Random values are almost never knife edges, so a wrong gate
+        bit cannot hide behind the classifier."""
+        rng = np.random.default_rng(5)
+        b = random_batch(rng, W=4, R=256, F=14)
+        b.rowmask[:] = 1.0
+        knife = knife_edge(b, 1.5)
+        assert knife.mean() < 1e-3
+        want = eval_gates_np(b, 1.5)
+        np.testing.assert_array_equal(gates_f32_interpreted(b, 1.5)[~knife],
+                                      want[~knife])
+
+    def test_compiled_kernel_needs_a_tpu(self):
+        """Off-TPU, backend="pallas" without interpret mode raises instead
+        of running the interpreter (or numpy) in the chip's place."""
+        rng = np.random.default_rng(1)
+        b = random_batch(rng, W=1, R=8, F=3)
+        with pytest.raises(RuntimeError, match="interpret=True"):
+            eval_gates(b.v, b.peer_vsum, b.inter_cnt, b.intra_cnt, b.rowmask,
+                       b.vsum, b.q, b.numok, b.floor, peer_mean=1.5)
+        tasks = [TaskRecord(f"t{i}", "s", f"n{i % 3}", 0.0,
+                            30.0 if i == 0 else 10.0,
+                            features={"read_bytes": 100.0 + i})
+                 for i in range(12)]
+        an = BigRootsAnalyzer(SPARK_FEATURES, backend="pallas",
+                              backend_min_rows=0)
+        w = fill_window(StageRecord("s", tasks), rng, 0.9)
+        with pytest.raises(RuntimeError, match="interpret=True"):
+            an.analyze_stage(w)
 
 
 class TestAnalyzerBackendEquivalence:
@@ -332,7 +412,7 @@ class TestFleetSweep:
         ]
         for backend in ("jax", "pallas"):
             an = BigRootsAnalyzer(SPARK_FEATURES, backend=backend,
-                                  backend_min_rows=0)
+                                  backend_min_rows=0, kernel_interpret=True)
             ref = BigRootsAnalyzer(SPARK_FEATURES)
             want = [found_set(ref.analyze_stage(w).root_causes)
                     for w in windows]

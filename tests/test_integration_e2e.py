@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import requires_grad_through_barrier
 
 from repro.launch.train import build_argparser, run
 
@@ -25,7 +24,6 @@ def make_args(**overrides):
 
 
 @pytest.mark.slow
-@requires_grad_through_barrier
 class TestTrainDriver:
     def test_loss_decreases_and_trace_emitted(self, tmp_path):
         args = make_args(arch="mamba2_130m",

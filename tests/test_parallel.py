@@ -10,9 +10,9 @@ import textwrap
 
 import jax
 import pytest
-from conftest import requires_grad_through_barrier
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import Model, smoke_variant
 from repro.parallel.sharding import param_spec
 
@@ -33,7 +33,7 @@ def run_virtual(script: str, devices: int = 8) -> str:
 
 class TestParamSpecRules:
     def setup_method(self):
-        self.mesh = jax.make_mesh((1, 1), ("data", "model"))
+        self.mesh = make_mesh((1, 1), ("data", "model"))
 
     def test_attention_specs(self):
         cfg = get_config("granite_8b")
@@ -44,7 +44,7 @@ class TestParamSpecRules:
             jax.sharding.PartitionSpec("model", None)
 
     def test_kv_replication_rule(self):
-        mesh16 = jax.make_mesh((1, 1), ("data", "model"))
+        mesh16 = make_mesh((1, 1), ("data", "model"))
         cfg = get_config("glm4_9b")  # kv=2
 
         class FakeMesh:
@@ -70,11 +70,11 @@ class TestParamSpecRules:
 
 @pytest.mark.slow
 class TestVirtualMesh:
-    @requires_grad_through_barrier
     def test_sharded_train_step_matches_single_device(self):
         """2×4 mesh train step ≡ single-device train step (same loss)."""
         run_virtual("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.configs import get_config
             from repro.models import Model, smoke_variant
@@ -96,7 +96,7 @@ class TestVirtualMesh:
             _, m_single = jax.jit(step)(s0, batch)
 
             # 2x4 mesh
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             st = abstract_state(model, opt)
             sh = state_shardings(st, cfg, mesh)
             s1 = init_state(model, jax.random.key(0), opt)
@@ -116,16 +116,16 @@ class TestVirtualMesh:
     def test_compressed_allreduce_shardmap(self):
         run_virtual("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from functools import partial
             from jax.sharding import PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
             from repro.parallel.compress import compressed_allreduce_mean
 
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             x = jnp.arange(8 * 64, dtype=jnp.float32).reshape(8, 64) / 100.0
 
-            @partial(shard_map, mesh=mesh, in_specs=P("data", None),
-                     out_specs=P("data", None), check_rep=False)
+            @partial(jax.shard_map, mesh=mesh, in_specs=P("data", None),
+                     out_specs=P("data", None), check_vma=False)
             def f(xs):
                 return compressed_allreduce_mean(xs[0], "data")[None]
 
@@ -142,10 +142,11 @@ class TestVirtualMesh:
     def test_pipeline_parallel(self):
         run_virtual("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.parallel.pipeline import pipeline_apply, stage_split
 
             assert stage_split(10, 4) == [3, 3, 2, 2]
-            mesh = jax.make_mesh((4,), ("pipe",))
+            mesh = make_mesh((4,), ("pipe",))
             n_stages, n_micro, mb, d = 4, 8, 2, 16
             keys = jax.random.split(jax.random.key(0), n_stages)
             ws = jnp.stack([
@@ -165,13 +166,13 @@ class TestVirtualMesh:
             print("PIPELINE_OK")
         """)
 
-    @requires_grad_through_barrier
     def test_small_dryrun_cell_on_8_devices(self):
         """End-to-end lower+compile of a reduced arch on a 2x4 mesh."""
         run_virtual("""
             import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.configs import get_config, SHAPES
+            from repro.launch.mesh import make_mesh
             from repro.models import Model, smoke_variant
             from repro.parallel.sharding import param_shardings
             from dataclasses import replace
@@ -179,7 +180,7 @@ class TestVirtualMesh:
             cfg = replace(smoke_variant(get_config("granite_moe_1b_a400m")),
                           moe_impl="dense")
             model = Model(cfg)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             params = model.abstract_params()
             p_sh = param_shardings(params, cfg, mesh)
             tokens = jax.ShapeDtypeStruct((8, 32), jax.numpy.int32)

@@ -59,6 +59,7 @@ class TestEpMoe:
         env["PYTHONPATH"] = os.path.join(repo, "src")
         script = textwrap.dedent("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from dataclasses import replace
             from repro.configs import get_config
             from repro.models import smoke_variant
@@ -67,7 +68,7 @@ class TestEpMoe:
 
             cfg = replace(smoke_variant(get_config("olmoe_1b_7b")),
                           moe_experts=8, moe_top_k=2)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             ep_moe.set_mesh(mesh)
             p = moe_init(jax.random.key(0), cfg)
             x = jax.random.normal(jax.random.key(1), (4, 8, cfg.d_model))

@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import requires_grad_through_barrier
 
 from repro.ckpt import CheckpointManager
 from repro.configs import get_config
@@ -242,7 +241,6 @@ class TestCompression:
         assert err <= 2 * scale  # bias does not accumulate across steps
 
     @pytest.mark.slow
-    @requires_grad_through_barrier
     def test_compressed_train_step_converges(self):
         cfg = smoke_variant(get_config("mamba2_130m"))
         model = Model(cfg)
@@ -288,7 +286,6 @@ class TestOptimizer:
         assert float(new_params["norm_scale"][0]) == 1.0  # exempt
 
     @pytest.mark.slow
-    @requires_grad_through_barrier
     def test_accum_matches_full_batch(self):
         cfg = smoke_variant(get_config("mamba2_130m"))
         model = Model(cfg)
