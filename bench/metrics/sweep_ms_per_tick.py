@@ -1,0 +1,7 @@
+"""Milliseconds inside ``Diagnosis.tick`` (the fleet sweep) per tick, from
+the benchmark's spans in the traced window."""
+
+
+def read(run: dict) -> float | None:
+    ms = sum(e - s for s, e in run["spans"].get("bench.sweep", ())) / 1e6
+    return ms / run["ticks"] if run["ticks"] else None
