@@ -35,6 +35,7 @@ from typing import Iterable, Protocol, Sequence
 
 import numpy as np
 
+from ..spans import span
 from .features import FeatureKind, FeatureSchema
 from .frame import StageFrame, as_frame
 from .records import StageRecord, TaskRecord, Trace
@@ -442,6 +443,9 @@ class BigRootsAnalyzer:
         self.kernel_interpret = bool(kernel_interpret)
         self._tl_cursor = None
         self._pack_scratch = None  # reused FleetGateBatch (see pack_windows)
+        self.stragglers_packed = 0     # straggler rows packed for the gates
+        self.gate_launches = 0         # packed gate evaluations
+        self.gate_rows_launched = 0    # W x padded R over those evaluations
 
     # -- public API ----------------------------------------------------------
     def analyze(self, trace) -> list[StageAnalysis]:
@@ -559,28 +563,29 @@ class BigRootsAnalyzer:
         if self.schema.signature != w.schema.signature:
             # Foreign schema: fall back through the sealed frame.
             return self.analyze_stage(w.seal())
-        pre = self._window_prelude(w)
+        with span("bigroots.prep"):
+            pre = self._window_prelude(w)
         if isinstance(pre, StageAnalysis):
             return pre
         n, median, s_rows, straggler_ids, scales_fn = pre
         V = w.v[s_rows]                          # [s, F] gate-space values
         # quantiles() itself falls back to exact below MIN_SKETCH_SAMPLES
         # live rows or on a λq mismatch with the window's sketch.
-        q = w.quantiles(self.thresholds.quantile,
-                        exact=self.window_exact_quantiles)
+        with span("bigroots.quantiles"):
+            q = w.quantiles(self.thresholds.quantile,
+                            exact=self.window_exact_quantiles)
         if self.backend != "numpy" and n >= self.backend_min_rows:
-            from .fleet import pack_windows
-
-            batch = pack_windows([(w, s_rows, n, V, q)], self.schema,
-                                 self.thresholds.time_floor,
-                                 scratch=self._pack_scratch)
-            self._pack_scratch = batch
+            batch = self._pack([(w, s_rows, n, V, q)])
             gbits = self._eval_gates_batch(batch)[0, : V.shape[0]]
             fired, gate_inter, gate_intra = _unpack_gbits(gbits)
         else:
-            fired, gate_inter, gate_intra = self._gates_numpy(w, s_rows, n, V, q)
-        return self._finish_window(w, n, median, s_rows, straggler_ids,
-                                   scales_fn, V, fired, gate_inter, gate_intra)
+            with span("bigroots.gates"):
+                fired, gate_inter, gate_intra = self._gates_numpy(
+                    w, s_rows, n, V, q)
+        with span("bigroots.emit"):
+            return self._finish_window(w, n, median, s_rows, straggler_ids,
+                                       scales_fn, V, fired, gate_inter,
+                                       gate_intra)
 
     def _window_prelude(
         self, w: SlidingStageWindow
@@ -634,21 +639,39 @@ class BigRootsAnalyzer:
         gate_intra &= ~np.isnan(intra)
         return gate_q & (gate_inter | gate_intra), gate_inter, gate_intra
 
+    def _pack(self, entries):
+        """Pack ``(window, s_rows, n, V, q)`` entries into one gate batch
+        (:func:`repro.core.fleet.pack_windows`), reusing the previous
+        batch's buffers."""
+        from .fleet import pack_windows
+
+        with span("bigroots.prep"):
+            batch = pack_windows(entries, self.schema,
+                                 self.thresholds.time_floor,
+                                 scratch=self._pack_scratch)
+        self._pack_scratch = batch
+        self.stragglers_packed += int(batch.counts.sum())
+        return batch
+
     def _eval_gates_batch(self, batch) -> np.ndarray:
         """Run a packed FleetGateBatch through the selected gate backend."""
         th = self.thresholds
-        if self.backend == "numpy":
-            from .fleet import eval_gates_np
+        W, R, _ = batch.shape
+        self.gate_launches += 1
+        self.gate_rows_launched += W * R
+        with span("bigroots.gates"):
+            if self.backend == "numpy":
+                from .fleet import eval_gates_np
 
-            return eval_gates_np(batch, th.peer_mean)
-        from ..kernels.bigroots_gates import eval_gates
+                return eval_gates_np(batch, th.peer_mean)
+            from ..kernels.bigroots_gates import eval_gates
 
-        return eval_gates(
-            batch.v, batch.peer_vsum, batch.inter_cnt, batch.intra_cnt,
-            batch.rowmask, batch.vsum, batch.q, batch.numok, batch.floor,
-            peer_mean=th.peer_mean, backend=self.backend,
-            interpret=self.kernel_interpret,
-        )
+            return eval_gates(
+                batch.v, batch.peer_vsum, batch.inter_cnt, batch.intra_cnt,
+                batch.rowmask, batch.vsum, batch.q, batch.numok, batch.floor,
+                peer_mean=th.peer_mean, backend=self.backend,
+                interpret=self.kernel_interpret,
+            )
 
     def analyze_fleet(self, windows) -> list[StageAnalysis]:
         """Fleet sweep: analyze many sliding windows with ONE batched gate
@@ -671,37 +694,34 @@ class BigRootsAnalyzer:
             ):
                 results[i] = self.analyze_stage(w)
                 continue
-            pre = self._window_prelude(w)
-            if isinstance(pre, StageAnalysis):
-                results[i] = pre
-                continue
-            n, median, s_rows, straggler_ids, scales_fn = pre
-            V = w.v[s_rows]
-            q = w.quantiles(self.thresholds.quantile,
-                            exact=self.window_exact_quantiles)
+            with span("bigroots.prep"):
+                pre = self._window_prelude(w)
+                if isinstance(pre, StageAnalysis):
+                    results[i] = pre
+                    continue
+                n, median, s_rows, straggler_ids, scales_fn = pre
+                V = w.v[s_rows]
+            with span("bigroots.quantiles"):
+                q = w.quantiles(self.thresholds.quantile,
+                                exact=self.window_exact_quantiles)
             entries.append((i, w, n, median, s_rows, straggler_ids, scales_fn,
                             V, q))
         if entries:
-            from .fleet import pack_windows
-
-            batch = pack_windows(
+            batch = self._pack(
                 [(w, s_rows, n, V, q)
-                 for (_, w, n, _, s_rows, _, _, V, q) in entries],
-                self.schema, self.thresholds.time_floor,
-                scratch=self._pack_scratch,
-            )
-            self._pack_scratch = batch
+                 for (_, w, n, _, s_rows, _, _, V, q) in entries])
             gbits = self._eval_gates_batch(batch)
             for k, (i, w, n, median, s_rows, straggler_ids, scales_fn, V, q) in (
                 enumerate(entries)
             ):
-                fired, gate_inter, gate_intra = _unpack_gbits(
-                    gbits[k, : V.shape[0]]
-                )
-                results[i] = self._finish_window(
-                    w, n, median, s_rows, straggler_ids, scales_fn, V,
-                    fired, gate_inter, gate_intra,
-                )
+                with span("bigroots.emit"):
+                    fired, gate_inter, gate_intra = _unpack_gbits(
+                        gbits[k, : V.shape[0]]
+                    )
+                    results[i] = self._finish_window(
+                        w, n, median, s_rows, straggler_ids, scales_fn, V,
+                        fired, gate_inter, gate_intra,
+                    )
         return results
 
     def _finish_window(

@@ -85,6 +85,7 @@ from typing import Iterator, Mapping, Sequence
 
 import numpy as np
 
+from ..spans import span
 from .features import FeatureKind, FeatureSchema
 from .frame import StageFrame
 from .records import TaskRecord
@@ -193,6 +194,9 @@ class SlidingStageWindow:
         self.retired_total = 0
         self.late_drops = 0
         self.compactions = 0
+        self.sketch_rows = 0             # rows added to the P² sketch one by one
+        self.sketch_anchors = 0          # exact re-anchors of the sketch
+        self.anchor_rows = 0             # live rows those re-anchors sorted
 
     # -- ingest ------------------------------------------------------------
     def add_row(
@@ -251,6 +255,7 @@ class SlidingStageWindow:
         self._node_cnt[code] += 1.0
         self._node_vsum[code] += v_row
         self._sketch.add(v_row)
+        self.sketch_rows += 1
         self._q_cache = None
         self._enforce_max_rows()
         self._maybe_anchor()
@@ -372,8 +377,10 @@ class SlidingStageWindow:
         self.locality_sum += float(loc.sum())
         self._scatter(codes, v, 1.0)
         if m <= self.p2_batch_limit:
-            for row in v:
-                self._sketch.add(row)
+            with span("bigroots.sketch"):
+                for row in v:
+                    self._sketch.add(row)
+            self.sketch_rows += m
         else:
             self._sketch_lag += m
         self._q_cache = None
@@ -586,7 +593,10 @@ class SlidingStageWindow:
         return self._q_cache
 
     def _anchor_sketch(self) -> None:
-        self._sketch.reset_from(self.live_v())
+        with span("bigroots.sketch"):
+            self._sketch.reset_from(self.live_v())
+        self.sketch_anchors += 1
+        self.anchor_rows += self.live_count
         self._sketch_lag = 0
         self._q_cache = None
 
@@ -868,6 +878,11 @@ class StreamingTraceStore:
     for reports and persistence.
     """
 
+    #: Per-window counters that :meth:`counters` sums over the live windows
+    #: and every window dropped before them.
+    WINDOW_COUNTERS = ("sketch_rows", "sketch_anchors", "anchor_rows",
+                       "late_drops")
+
     def __init__(
         self,
         schema: FeatureSchema,
@@ -881,6 +896,7 @@ class StreamingTraceStore:
         self.max_rows = max_rows
         self.quantile = quantile
         self._windows: dict[str, SlidingStageWindow] = {}
+        self._dropped = dict.fromkeys(self.WINDOW_COUNTERS, 0)
 
     def add_row(
         self,
@@ -957,8 +973,23 @@ class StreamingTraceStore:
     def drop_stage(self, stage_id: str) -> bool:
         """Forget a stage's window entirely (fleet-aggregation retention:
         an always-on loop opens a new step-window stage every N steps and
-        must shed exhausted ones to stay bounded)."""
-        return self._windows.pop(stage_id, None) is not None
+        must shed exhausted ones to stay bounded).  Its counters live on
+        in :meth:`counters`."""
+        w = self._windows.pop(stage_id, None)
+        if w is None:
+            return False
+        for name in self._dropped:
+            self._dropped[name] += getattr(w, name)
+        return True
+
+    def counters(self) -> dict[str, int]:
+        """:attr:`WINDOW_COUNTERS` summed over every window this store
+        has held, dropped ones included."""
+        out = dict(self._dropped)
+        for w in self._windows.values():
+            for name in out:
+                out[name] += getattr(w, name)
+        return out
 
     def window(self, stage_id: str) -> SlidingStageWindow:
         return self._windows[stage_id]
@@ -1084,6 +1115,8 @@ class RootCauseStream:
         self.reemitted = 0
         self.forgotten = 0
         self.recovered_total = 0.0
+        self.windows_swept = 0           # changed windows handed to the sweep
+        self.seen_walked = 0             # cause keys visited by the forget pass
         # Per-stage content stamps for StreamingTraceStore sources: a
         # window whose (uid, total_added, retired_total) is unchanged since
         # the last step is skipped — its rows, and therefore its analysis,
@@ -1105,27 +1138,29 @@ class RootCauseStream:
             # exhausted stage windows, and re-analyzing frozen rows every
             # step both multiplies sweep cost by the retention cap and
             # re-confirms stale causes forever (defeating decay/forget).
-            all_windows = list(self.source.stages())
-            stamps = {
-                w.stage_id: (w.uid, w.total_added, w.retired_total)
-                for w in all_windows
-            }
-            # Row-stamp purity has one exception: Eq. 6 edge detection
-            # reads the live ResourceTimeline, whose samples covering a
-            # task's tail window ([end, end+edge_width]) arrive *after*
-            # the row does.  Until the fleet clock (max t_max) passes a
-            # window's last end + edge_width, its resource verdicts can
-            # still change, so it stays in the sweep even when unchanged.
-            settle = 0.0
-            if getattr(self.analyzer, "timelines", None) is not None:
-                th = getattr(self.analyzer, "thresholds", None)
-                settle = float(getattr(th, "edge_width", 0.0) or 0.0)
-            now = max((w.t_max for w in all_windows), default=-np.inf)
-            windows = [
-                w for w in all_windows
-                if self._window_stamps.get(w.stage_id) != stamps[w.stage_id]
-                or (settle > 0.0 and w.t_max + settle > now)
-            ]
+            with span("bigroots.prep"):
+                all_windows = list(self.source.stages())
+                stamps = {
+                    w.stage_id: (w.uid, w.total_added, w.retired_total)
+                    for w in all_windows
+                }
+                # Row-stamp purity has one exception: Eq. 6 edge detection
+                # reads the live ResourceTimeline, whose samples covering a
+                # task's tail window ([end, end+edge_width]) arrive *after*
+                # the row does.  Until the fleet clock (max t_max) passes a
+                # window's last end + edge_width, its resource verdicts can
+                # still change, so it stays in the sweep even when unchanged.
+                settle = 0.0
+                if getattr(self.analyzer, "timelines", None) is not None:
+                    th = getattr(self.analyzer, "thresholds", None)
+                    settle = float(getattr(th, "edge_width", 0.0) or 0.0)
+                now = max((w.t_max for w in all_windows), default=-np.inf)
+                windows = [
+                    w for w in all_windows
+                    if self._window_stamps.get(w.stage_id) != stamps[w.stage_id]
+                    or (settle > 0.0 and w.t_max + settle > now)
+                ]
+            self.windows_swept += len(windows)
             fleet = getattr(self.analyzer, "analyze_fleet", None)
             if fleet is not None:
                 analyses = fleet(windows)
@@ -1146,35 +1181,40 @@ class RootCauseStream:
         step = self.steps
         decay = self.decay_steps
         fresh = []
-        for sa in analyses:
-            for cause in sa.root_causes:
-                st = self.seen.get(cause.key)
-                if st is None:
-                    self.seen[cause.key] = CauseState(
-                        first_step=step, last_confirmed=step
-                    )
-                    fresh.append(cause)
-                    continue
-                dormant = decay is not None and st.clean_windows(step) > decay
-                st.confirmations += 1
-                st.last_confirmed = step
-                if dormant:
-                    # Re-emergence after a clean spell: escalate (capped)
-                    # and re-emit.
-                    st.severity = min(st.severity + 1, self.max_severity)
-                    st.emits += 1
-                    self.reemitted += 1
-                    fresh.append(replace(cause, severity=st.severity))
+        with span("bigroots.emit"):
+            for sa in analyses:
+                for cause in sa.root_causes:
+                    st = self.seen.get(cause.key)
+                    if st is None:
+                        self.seen[cause.key] = CauseState(
+                            first_step=step, last_confirmed=step
+                        )
+                        fresh.append(cause)
+                        continue
+                    dormant = (decay is not None
+                               and st.clean_windows(step) > decay)
+                    st.confirmations += 1
+                    st.last_confirmed = step
+                    if dormant:
+                        # Re-emergence after a clean spell: escalate
+                        # (capped) and re-emit.
+                        st.severity = min(st.severity + 1, self.max_severity)
+                        st.emits += 1
+                        self.reemitted += 1
+                        fresh.append(replace(cause, severity=st.severity))
         if self.attributor is not None and fresh:
-            fresh = self._attribute(fresh)
+            with span("bigroots.whatif"):
+                fresh = self._attribute(fresh)
         self.emitted += len(fresh)
         if self.forget_steps is not None:
-            horizon = self.forget_steps
-            expired = [k for k, st in self.seen.items()
-                       if st.clean_windows(step) > horizon]
-            for k in expired:
-                del self.seen[k]
-            self.forgotten += len(expired)
+            with span("bigroots.emit"):
+                horizon = self.forget_steps
+                self.seen_walked += len(self.seen)
+                expired = [k for k, st in self.seen.items()
+                           if st.clean_windows(step) > horizon]
+                for k in expired:
+                    del self.seen[k]
+                self.forgotten += len(expired)
         return fresh
 
     def _attribute(self, fresh: list) -> list:

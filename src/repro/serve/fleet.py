@@ -50,6 +50,7 @@ from ..core.analyzer import (
 from ..core.features import FeatureSchema
 from ..core.whatif import WhatIfReplayer
 from ..core.window import RootCauseStream, StreamingTraceStore
+from ..spans import root, span
 from ..telemetry.events import (
     MAX_FORWARD_DEPTH,
     ForwardedDelta,
@@ -283,13 +284,18 @@ class FleetAggregator:
         windows.  Returns rows ingested (0 for duplicates/empty deltas).
         Wire payloads carrying the forwarded-envelope magic are unwrapped
         recursively (see the class docstring)."""
+        with root("bigroots.ingest"):
+            return self._ingest(delta, _depth)
+
+    def _ingest(self, delta: StepDelta | bytes, depth: int) -> int:
         raw: bytes | None = None
         if isinstance(delta, (bytes, bytearray, memoryview)):
             raw = bytes(delta)
             if ForwardedDelta.is_forwarded(raw):
-                return self._ingest_forwarded(raw, _depth)
+                return self._ingest_forwarded(raw, depth)
             self.bytes_ingested += len(raw)
-            delta = StepDelta.from_bytes(raw)
+            with span("bigroots.decode"):
+                delta = StepDelta.from_bytes(raw)
         boots = self.host_seq.setdefault(delta.host, {})
         last_seq = boots.get(delta.boot)
         if last_seq is not None and delta.seq <= last_seq:
@@ -328,7 +334,8 @@ class FleetAggregator:
                 self.stale_stage_drops += len(delta.stages) - len(live_stages)
                 delta = StepDelta(delta.host, delta.seq, live_stages,
                                   boot=delta.boot, causes=delta.causes)
-        rows = delta.apply_to(self.store)
+        with span("bigroots.merge"):
+            rows = delta.apply_to(self.store)
         # Commit the watermark only after the delta applied: a delta that
         # raised mid-apply stays un-acked, so its at-least-once retry is
         # re-attempted instead of dropped as a duplicate (a partial first
@@ -517,6 +524,10 @@ class FleetAggregator:
         cordon + re-mesh plan in the same tick it was detected.  Pass the
         caller's measured per-step wall time as ``step_time`` to feed the
         policy's rollback verifier."""
+        with root("bigroots.step", tick=self._ticks + 1):
+            return self._step(step_time)
+
+    def _step(self, step_time: float | None) -> list:
         causes = self.stream.step()
         self._ticks += 1
         for cause in causes:
@@ -586,6 +597,27 @@ class FleetAggregator:
             return
         for w in windows:
             w.advance(now)
+
+    def counters(self) -> dict[str, int | float]:
+        """Every counter of the aggregator, its stream, its windows
+        (dropped ones included) and its analyzer, by name: the ingest
+        and sweep work done so far, for an operator or a benchmark to
+        difference across an interval."""
+        names = ("deltas_ingested", "rows_ingested", "bytes_ingested",
+                 "forwarded_frames", "forwarded_bytes", "duplicate_drops",
+                 "host_restarts", "stages_dropped", "stale_stage_drops",
+                 "reorder_holds", "reorder_flushes",
+                 "remote_causes_ingested", "host_dropouts", "host_rejoins")
+        out = {name: getattr(self, name) for name in names}
+        out["ticks"] = self._ticks
+        out.update(self.store.counters())
+        for name in ("windows_swept", "emitted", "reemitted", "forgotten",
+                     "seen_walked", "recovered_total"):
+            out[name] = getattr(self.stream, name)
+        for name in ("stragglers_packed", "gate_launches",
+                     "gate_rows_launched"):
+            out[name] = getattr(self.analyzer, name, 0)
+        return out
 
     @property
     def last_analysis(self):
@@ -894,8 +926,6 @@ class TreeAggregator(FleetAggregator):
         # (pid, raw) accepted but not yet enveloped / envelopes in flight.
         self._pending: list[tuple[int | None, bytes]] = []
         self._inflight: dict[int, list[tuple[int | None, bytes]]] = {}
-        self.forwards_sent = 0
-        self.forward_acks = 0
         self.recovered_payloads = 0
         self.recovered_rows = 0
         self._owns_parent = False
@@ -949,13 +979,11 @@ class TreeAggregator(FleetAggregator):
             self._inflight[self._fwd_seq] = batch
             ok = self.parent.send_bytes(env.to_bytes(), self.boot,
                                         self._fwd_seq)
-            self.forwards_sent += 1
             sent += 1
             if ok and not hasattr(self.parent, "take_acks"):
                 # Ack-less parent (e.g. a shm ring): a successful push is
                 # the delivery — retire immediately.
                 self._inflight.pop(self._fwd_seq, None)
-                self.forward_acks += 1
                 if self.journal is not None:
                     self.journal.note_ack(self.boot, self._fwd_seq)
         self._drain_acks()
@@ -969,10 +997,9 @@ class TreeAggregator(FleetAggregator):
         for boot, seq in take():
             if boot != self.boot:
                 continue
-            if self._inflight.pop(seq, None) is not None:
-                self.forward_acks += 1
-                if self.journal is not None:
-                    self.journal.note_ack(boot, seq)
+            if (self._inflight.pop(seq, None) is not None
+                    and self.journal is not None):
+                self.journal.note_ack(boot, seq)
 
     @property
     def pending_forwards(self) -> int:

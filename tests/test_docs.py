@@ -269,6 +269,21 @@ class TestOperationsDoc:
                        "pack_sequences"):
             assert needle in text, f"operations.md lost {needle!r}"
 
+    def test_tracing_the_aggregator_section(self):
+        """The tracing guide: how spans switch on, every span name, and
+        the counters an operator watches."""
+        text = _read(OPS)
+        for needle in ("Tracing the aggregator", "repro.spans.enable()",
+                       "jax.profiler", "bigroots.ingest", "bigroots.decode",
+                       "bigroots.merge", "bigroots.sketch", "bigroots.step",
+                       "bigroots.prep", "bigroots.quantiles",
+                       "bigroots.gates", "bigroots.emit", "bigroots.whatif",
+                       "repro.serve.FleetAggregator.counters",
+                       "sketch_rows", "stragglers_packed",
+                       "gate_rows_launched", "seen_walked",
+                       "pending_forwards"):
+            assert needle in text, f"operations.md lost {needle!r}"
+
     def test_readme_links_here_for_rebaseline(self):
         """The re-baseline workflow moved here; the README must keep a
         pointer instead of a divergent copy."""
