@@ -54,7 +54,10 @@ retirement and bulk :meth:`add_rows` accumulate *sketch lag*; once lag
 exceeds ``sketch_lag_frac ×`` live rows the next :meth:`quantiles` call
 re-anchors the sketch exactly from the live window (amortized O(changed)).
 Below :data:`~repro.core.sketch.MIN_SKETCH_SAMPLES` live rows the gate is
-exact ``np.quantile`` — tiny stages answer seed-identically.
+exact ``np.quantile`` — tiny stages answer seed-identically.  A window built
+with ``quantile=None`` keeps no sketch at all (for an owner whose analyzer
+always reads λq exactly): no per-row adds, no lag, no re-anchors, and every
+:meth:`~SlidingStageWindow.quantiles` read is exact.
 
 Multi-host merge
 ----------------
@@ -114,7 +117,8 @@ class SlidingStageWindow:
     quantile:
         λq tracked by the P² sketch (must match the analyzer's
         ``thresholds.quantile`` for the sketch to serve the gate; a
-        mismatched query falls back to the exact computation).
+        mismatched query falls back to the exact computation).  ``None``
+        keeps no sketch: every :meth:`quantiles` read is exact.
     sketch_lag_frac:
         Re-anchor the sketch from live rows once
         ``changed-rows-since-anchor > frac × live``.
@@ -134,7 +138,7 @@ class SlidingStageWindow:
         *,
         span: float | None = None,
         max_rows: int | None = None,
-        quantile: float = 0.9,
+        quantile: float | None = 0.9,
         sketch_lag_frac: float = 1.0,
         p2_batch_limit: int = 32,
     ) -> None:
@@ -145,7 +149,7 @@ class SlidingStageWindow:
         self.uid = next(SlidingStageWindow._uids)
         self.span = None if span is None else float(span)
         self.max_rows = None if max_rows is None else int(max_rows)
-        self.quantile = float(quantile)
+        self.quantile = None if quantile is None else float(quantile)
         self.sketch_lag_frac = float(sketch_lag_frac)
         self.p2_batch_limit = int(p2_batch_limit)
         self._col = schema.col_index
@@ -184,7 +188,9 @@ class SlidingStageWindow:
         self.vsumsq = np.zeros(k, dtype=np.float64)
         self.locality_sum = 0.0
 
-        self._sketch = P2ColumnSketch(self.quantile, k)
+        self._sketch = (
+            None if self.quantile is None else P2ColumnSketch(self.quantile, k)
+        )
         self._sketch_lag = 0
         self._q_cache: np.ndarray | None = None
 
@@ -254,8 +260,9 @@ class SlidingStageWindow:
         self.locality_sum += locality
         self._node_cnt[code] += 1.0
         self._node_vsum[code] += v_row
-        self._sketch.add(v_row)
-        self.sketch_rows += 1
+        if self._sketch is not None:
+            self._sketch.add(v_row)
+            self.sketch_rows += 1
         self._q_cache = None
         self._enforce_max_rows()
         self._maybe_anchor()
@@ -376,13 +383,14 @@ class SlidingStageWindow:
         self.vsumsq += (v * v).sum(axis=0)
         self.locality_sum += float(loc.sum())
         self._scatter(codes, v, 1.0)
-        if m <= self.p2_batch_limit:
-            with span("bigroots.sketch"):
-                for row in v:
-                    self._sketch.add(row)
-            self.sketch_rows += m
-        else:
-            self._sketch_lag += m
+        if self._sketch is not None:
+            if m <= self.p2_batch_limit:
+                with span("bigroots.sketch"):
+                    for row in v:
+                        self._sketch.add(row)
+                self.sketch_rows += m
+            else:
+                self._sketch_lag += m
         self._q_cache = None
         self._enforce_max_rows()
         self._maybe_anchor()
@@ -564,7 +572,8 @@ class SlidingStageWindow:
         self._scatter(self._node_codes[idx], v, -1.0)
         self._tombstone(idx)
         self.retired_total += idx.size
-        self._sketch_lag += idx.size
+        if self._sketch is not None:
+            self._sketch_lag += idx.size
         self._q_cache = None
         # Compact when dead rows dominate (keeps live extraction O(2·live)).
         if self._n - self.live_count > max(self.live_count, self._INITIAL):
@@ -575,14 +584,19 @@ class SlidingStageWindow:
         """Per-column λq gate thresholds over the live window.
 
         Sketch estimate by default; exact ``np.quantile`` when ``exact``,
-        when the live window is below :data:`MIN_SKETCH_SAMPLES` rows, or
-        when ``q`` differs from the sketched quantile.  A sketch whose lag
-        (rows added in bulk / retired since the last anchor) exceeds
-        ``sketch_lag_frac × live`` is re-anchored exactly first.
+        when the live window is below :data:`MIN_SKETCH_SAMPLES` rows,
+        when ``q`` differs from the sketched quantile, or when the window
+        keeps no sketch (``quantile=None``; ``q`` is then required).  A
+        sketch whose lag (rows added in bulk / retired since the last
+        anchor) exceeds ``sketch_lag_frac × live`` is re-anchored exactly
+        first.
         """
         q = self.quantile if q is None else float(q)
+        if q is None:
+            raise ValueError("a window without a λq sketch needs an explicit q")
         if (
             exact
+            or self._sketch is None
             or q != self.quantile
             or self.live_count < MIN_SKETCH_SAMPLES
         ):
@@ -593,6 +607,8 @@ class SlidingStageWindow:
         return self._q_cache
 
     def _anchor_sketch(self) -> None:
+        if self._sketch is None:
+            return
         with span("bigroots.sketch"):
             self._sketch.reset_from(self.live_v())
         self.sketch_anchors += 1
@@ -604,7 +620,7 @@ class SlidingStageWindow:
         """Re-anchor the sketch at ingest time once the lag budget is spent,
         or when bulk ingest outran an uninitialized sketch (maintenance
         belongs to the write path; reads stay O(1))."""
-        if self.live_count < MIN_SKETCH_SAMPLES:
+        if self._sketch is None or self.live_count < MIN_SKETCH_SAMPLES:
             return
         if (
             self._sketch_lag > self.sketch_lag_frac * self.live_count
@@ -875,7 +891,8 @@ class StreamingTraceStore:
     idiom, but ``stages()`` yields the :class:`SlidingStageWindow` objects
     themselves, so ``BigRootsAnalyzer.analyze(store)`` runs the incremental
     per-window path; ``frames()``/``dump_jsonl`` provide sealed snapshots
-    for reports and persistence.
+    for reports and persistence.  ``quantile=None`` builds every window
+    without a λq sketch (see :class:`SlidingStageWindow`).
     """
 
     #: Per-window counters that :meth:`counters` sums over the live windows
@@ -889,7 +906,7 @@ class StreamingTraceStore:
         *,
         span: float | None = None,
         max_rows: int | None = None,
-        quantile: float = 0.9,
+        quantile: float | None = 0.9,
     ) -> None:
         self.schema = schema
         self.span = span

@@ -214,9 +214,14 @@ class FleetAggregator:
     ) -> None:
         self.schema = schema
         self.analyzer = analyzer if analyzer is not None else BigRootsAnalyzer(schema)
-        quantile = getattr(
-            getattr(self.analyzer, "thresholds", None), "quantile", 0.9
-        )
+        if getattr(self.analyzer, "window_exact_quantiles", False):
+            # An analyzer that always reads λq exactly never reads a
+            # window's P² sketch, so the windows keep none.
+            quantile = None
+        else:
+            quantile = getattr(
+                getattr(self.analyzer, "thresholds", None), "quantile", 0.9
+            )
         self.store = StreamingTraceStore(
             schema, span=span, max_rows=max_rows, quantile=quantile,
         )

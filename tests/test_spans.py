@@ -215,3 +215,76 @@ def test_counters_agree_with_the_sweep():
     assert c["seen_walked"] == seen and c["forgotten"] == 0
     assert c["ticks"] == 6 and c["emitted"] > 0
     assert c["recovered_total"] > 0.0
+
+
+def cause_stream(causes):
+    return [(c.task_id, c.stage_id, c.node, c.feature, c.kind, c.value,
+             c.peer_groups, c.severity,
+             None if c.attribution is None else repr(c.attribution))
+            for c in causes]
+
+
+def sketch_run(exact, keep_sketch, tmp_path):
+    """Drive a journaled ``TreeAggregator`` root through small blocks, a
+    block above ``p2_batch_limit``, ``max_rows`` retirement, compaction, a
+    stage drop and a store merge, then recover a second incarnation from
+    its journal and drive that one tick more.  ``keep_sketch`` builds the
+    windows with a P² sketch whatever the analyzer reads: the reference
+    that windows without one must match."""
+    from repro.core.window import StreamingTraceStore
+    from repro.serve.fleet import TreeAggregator
+
+    def build(boot):
+        analyzer = BigRootsAnalyzer(JAX_FEATURES, backend="numpy",
+                                    window_exact_quantiles=exact
+                                    and not keep_sketch)
+        agg = TreeAggregator(JAX_FEATURES, analyzer, name="root", boot=boot,
+                             journal=str(tmp_path / f"{keep_sketch}.journal"),
+                             attribution=True, max_stages=2, max_rows=150)
+        analyzer.window_exact_quantiles = exact
+        return agg
+
+    agg = build(1)
+    rng = np.random.default_rng(9)
+    causes = []
+    for k in range(6):
+        payloads = tick_payloads(k, rng)
+        big = block(f"s{k // 2}", "bulk", k, 40, rng)
+        payloads.append(StepDelta("bulk", k + 1, [big], boot=1).to_bytes())
+        for p in payloads:
+            agg.ingest(p)
+        if k == 4:
+            side = StreamingTraceStore(JAX_FEATURES)
+            StepDelta("side", 1, [block("s2", "side", k, 24, rng)]).apply_to(
+                side)
+            assert agg.merge_stores(side) == 24
+        causes += agg.step()
+    counters = [agg.counters()]
+    agg.journal.close()
+    agg = build(2)
+    assert agg.recovered_rows > 0
+    for p in tick_payloads(6, rng):
+        agg.ingest(p)
+    causes += agg.step()
+    counters.append(agg.counters())
+    return cause_stream(causes), counters, agg
+
+
+@pytest.mark.parametrize("exact", [True, False], ids=["exact", "sketch"])
+def test_windows_keep_the_sketch_only_where_it_is_read(exact, tmp_path):
+    got, counters, agg = sketch_run(exact, False, tmp_path)
+    want, want_counters, _ = sketch_run(exact, True, tmp_path)
+    assert got and got == want
+    assert all((w._sketch is None) == exact for w in agg.store.stages())
+    for c, w in zip(counters, want_counters):
+        assert c["rows_ingested"] > 0 and c["stages_dropped"] > 0
+        assert w["sketch_rows"] > 0 and w["sketch_anchors"] > 0
+        assert w["anchor_rows"] > 0
+        sketch = {k: c[k] for k in ("sketch_rows", "sketch_anchors",
+                                    "anchor_rows")}
+        if exact:
+            assert sketch == dict.fromkeys(sketch, 0)
+        else:
+            assert sketch == {k: w[k] for k in sketch}
+        assert ({k: v for k, v in c.items() if k not in sketch}
+                == {k: v for k, v in w.items() if k not in sketch})

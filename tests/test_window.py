@@ -298,6 +298,36 @@ class TestTinyStageSketchFallback:
         )
 
 
+class TestSketchlessWindow:
+    def test_reads_exact_and_keeps_no_sketch(self):
+        """``quantile=None``: single rows, small and large blocks, ``max_rows``
+        retirement, compaction and a merge all leave the sketch counters at
+        0, and a read with ``exact=False`` is the exact quantile."""
+        rng = np.random.default_rng(11)
+        w = SlidingStageWindow("s", SPARK_FEATURES, max_rows=200,
+                               quantile=None)
+        for i in range(40):
+            w.add_row(f"t{i}", f"n{i % 3}", 0.0, rng.uniform(0.5, 2.0),
+                      features={"cpu": rng.random()})
+        for b, m in enumerate([8, 8, 64, 8, 300]):
+            starts = np.full(m, 1.0 + b)
+            w.add_rows([f"b{b}-{i}" for i in range(m)],
+                       [f"n{i % 3}" for i in range(m)], starts,
+                       starts + rng.uniform(0.5, 2.0, m),
+                       feature_columns={"cpu": rng.random(m)})
+        other = SlidingStageWindow("s", SPARK_FEATURES)
+        for i in range(30):
+            other.add_row(f"o{i}", "n9", 9.0, 9.0 + rng.uniform(0.5, 2.0))
+        assert w.merge(other) == 30
+        assert w.retired_total > 0 and w.compactions > 0
+        assert (w.sketch_rows, w.sketch_anchors, w.anchor_rows) == (0, 0, 0)
+        for q in (0.5, 0.9):
+            np.testing.assert_array_equal(
+                w.quantiles(q), exact_quantile(w.live_v(), q))
+        with pytest.raises(ValueError):
+            w.quantiles()
+
+
 class TestStreamingTraceStore:
     def test_routes_stages_and_analyzes_incrementally(self):
         store = StreamingTraceStore(SPARK_FEATURES, max_rows=100)
