@@ -1,9 +1,5 @@
-"""Share of the traced window in which no operation ran on the device:
-1 - (union of the device's op intervals) / window, in percent."""
+"""``device_idle_share.backlog`` in the open-loop cells, where it moves
+the tick latency: the same reader."""
+import run
 
-
-def read(run: dict) -> float | None:
-    t = run["trace"]
-    if not t["window_s"]:
-        return None
-    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
+read = run.load_reader("device_idle_share.backlog")

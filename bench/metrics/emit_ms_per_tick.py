@@ -1,0 +1,6 @@
+"""Milliseconds in the program's ``bigroots.emit`` spans per tick of the
+traced window: the Eq. 6/7 finish and cause building of each window, and
+the stream's emit-once, decay and forget passes."""
+import program_spans
+
+read = program_spans.reader(__file__, "bigroots.emit", "tick")

@@ -1,0 +1,6 @@
+"""Milliseconds in the program's ``bigroots.whatif`` spans per tick of the
+traced window: what-if attribution of the tick's fresh causes
+(``RootCauseStream._attribute``)."""
+import program_spans
+
+read = program_spans.reader(__file__, "bigroots.whatif", "tick")

@@ -2,7 +2,8 @@
 """The program's own spans (``bigroots.*``, written by ``repro.spans``)
 in a traced run, and a check of what they cost.
 
-The per-layer metrics that split ingest and the sweep read them.
+The per-layer metrics that split ingest and the sweep read them, per
+thousand rows (backlogged cells) or per tick (open-loop cells).
 ``run.py`` writes a traced run's profile under
 ``<checkout>/bench_out/trace`` and reduces it to its own ``bench.*``
 spans; :func:`load` reads the ``bigroots.*`` spans of the same profile,
@@ -95,14 +96,25 @@ def self_seconds(spans: dict, name: str, children=()) -> float:
     return total / 1e9
 
 
-def ms_per_krow(run: dict, root: Path, name: str, children=()) -> float | None:
+def ms_per(run: dict, root: Path, name: str, per: str,
+           children=()) -> float | None:
     """Milliseconds of span ``name`` (its self time, without
-    ``children``) per thousand rows, or None where the run has no such
+    ``children``) per thousand rows (``per="krow"``) or per tick
+    (``per="tick"``) of the window, or None where the run has no such
     span."""
     spans = load(run, root)
-    if not spans.get(name) or not run["rows"]:
+    n = run["rows"] / 1e3 if per == "krow" else run["ticks"]
+    if not spans.get(name) or not n:
         return None
-    return self_seconds(spans, name, children) * 1e3 / (run["rows"] / 1e3)
+    return self_seconds(spans, name, children) * 1e3 / n
+
+
+def reader(path: str, name: str, per: str, children=()):
+    """The ``read(run)`` of the metric file at ``path``, which lies under
+    ``<checkout>/bench/metrics``: :func:`ms_per` on that checkout's
+    profile."""
+    root = Path(path).parents[2]
+    return lambda run: ms_per(run, root, name, per, children)
 
 
 def _span_ns(trace_dir: Path) -> dict[str, float]:

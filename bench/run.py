@@ -26,6 +26,7 @@ import faulthandler  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
+import os  # noqa: E402
 import signal  # noqa: E402
 import sys  # noqa: E402
 from contextlib import nullcontext  # noqa: E402
@@ -47,6 +48,12 @@ SETTLED_TICKS = 16
 #: the window, where nothing may compile.
 TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 _traced = [0, 0]                 # traces counted, listener registered
+#: Seconds that the device check spent importing JAX and finding the
+#: devices (the TPU runtime's start): the first parts of set-up.
+_setup_marks: dict[str, float] = {}
+#: The TPU runtime pins a host staging buffer for transfers when it
+#: starts; the gate launches move a few MB, so a small one serves.
+os.environ.setdefault("TPU_PREMAPPED_BUFFER_SIZE", str(256 << 20))
 
 
 def _count_trace(event: str, _secs: float, **_kw) -> None:
@@ -100,9 +107,13 @@ def load_reader(name: str, root: Path = ROOT):
 def check_device(chips: int) -> tuple[dict, dict]:
     """The device as JAX reports it and its peaks; raises BenchError off
     a TPU, short of chips, or on a device kind with no peaks."""
+    t = time.perf_counter()
     import jax
 
+    _setup_marks["jax_import"] = time.perf_counter() - t
+    t = time.perf_counter()
     devs = jax.devices()
+    _setup_marks["device_init"] = time.perf_counter() - t
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs)}
     if device["platform"] != "tpu":
@@ -274,7 +285,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     generator = gen.Generator(cfg, traffic, seed, workers=workers,
                               ahead=ahead)
     got: list[list[dict]] = []
-    parts = {"start": time.perf_counter() - t_start}
+    parts = dict(_setup_marks, start=time.perf_counter() - t_start)
     try:
         svc = Service(cfg, interpret=interpret)
         for name, fn in hooks.items():

@@ -10,7 +10,8 @@ another: this process never touches JAX, so each child has the chip to
 itself.  One JSON line per rate: tick latency p50/p90, the lag growth
 (median latency of the window's last fifth of ticks minus its first
 fifth: near 0 while the rate is sustained, growing with the run above
-it), CPU per row and whether the run was correct.  The cell's traffic
+it), CPU per row, the ticks the generator had not built when due, and
+whether the run was correct.  The cell's traffic
 file then takes 4/5 of the highest rate that sustains.
 """
 import argparse
@@ -35,7 +36,9 @@ def one_rate(workload: str, seed: int, seconds: float, rate: float) -> dict:
             "lag_growth_ms": res["info"]["lag_growth_ms"],
             "cpu_ms_per_krow": m.get("cpu_ms_per_krow"),
             "setup_s": m.get("setup_s"),
-            "traced_in_window": res["info"]["traced_in_window"]}
+            "late_ticks": res["info"]["late_ticks"],
+            "traced_in_window": res["info"]["traced_in_window"],
+            "memory_peak_bytes": res["device"]["memory_peak_bytes"]}
 
 
 def main() -> int:

@@ -1,7 +1,8 @@
-"""The readers of the program's own spans (``program_spans.py`` and the
-eight ``*_ms_per_krow`` metrics that split ingest and the sweep): on
-hand-built runs, on a recorded trace of a program without spans, and in
-a traced CPU rehearsal of ``megascale_12k.incident``."""
+"""The readers of the program's own spans (``program_spans.py``, and the
+seven metrics that split ingest and the sweep, per thousand rows in a
+backlogged cell and per tick in an open-loop one): on hand-built runs, on
+a recorded trace of a program without spans, and in a traced CPU
+rehearsal of each cell, the held one with its entries added."""
 import json
 import shutil
 from pathlib import Path
@@ -14,15 +15,18 @@ import trace_reduce
 
 ROOT = Path(run.ROOT)
 TRACE = Path(__file__).parent / "data" / "gates_small.xplane.pb"
-INGEST = ("decode", "merge", "sketch")
+INGEST = ("decode", "merge")
 SWEEP = ("prep", "quantiles", "gates_host", "emit", "whatif")
 NAMES = [f"{m}_ms_per_krow" for m in INGEST + SWEEP]
+TICK_NAMES = [f"{m}_ms_per_tick" for m in INGEST + SWEEP]
 
 
-def hand_run(rows=2_000):
-    """Two payloads and one sweep: times in ns, so 1e6 ns is 1 ms."""
+def hand_run(rows=2_000, ticks=4):
+    """Two payloads and one sweep: times in ns, so 1e6 ns is 1 ms.  The
+    sketch spans stand for the P² work a window that keeps a sketch does
+    inside the merge and the quantiles."""
     ms = 1_000_000
-    return {"rows": rows, "program_spans": {
+    return {"rows": rows, "ticks": ticks, "program_spans": {
         "bigroots.decode": [(0, 2 * ms), (10 * ms, 13 * ms)],
         "bigroots.merge": [(2 * ms, 8 * ms), (13 * ms, 17 * ms)],
         "bigroots.sketch": [(3 * ms, 7 * ms), (14 * ms, 15 * ms),
@@ -39,24 +43,33 @@ def hand_run(rows=2_000):
     ("decode_ms_per_krow", 5 / 2),
     # merge: 10 ms less the 5 ms of sketch inside it.
     ("merge_ms_per_krow", 5 / 2),
-    ("sketch_ms_per_krow", 6 / 2),
     ("prep_ms_per_krow", 3 / 2),
     # quantiles: 6 ms less the 1 ms re-anchor inside the second one.
     ("quantiles_ms_per_krow", 5 / 2),
     ("gates_host_ms_per_krow", 1 / 2),
     ("emit_ms_per_krow", 2 / 2),
     ("whatif_ms_per_krow", 1 / 2),
+    # Per tick: the same self times over the 4 ticks.
+    ("decode_ms_per_tick", 5 / 4),
+    ("merge_ms_per_tick", 5 / 4),
+    ("prep_ms_per_tick", 3 / 4),
+    ("quantiles_ms_per_tick", 5 / 4),
+    ("gates_host_ms_per_tick", 1 / 4),
+    ("emit_ms_per_tick", 2 / 4),
+    ("whatif_ms_per_tick", 1 / 4),
 ])
 def test_reader_on_hand_built_run(name, want):
     assert run.load_reader(name)(hand_run()) == pytest.approx(want)
 
 
-def test_reader_without_the_span_or_rows():
+@pytest.mark.parametrize("name,empty", [("whatif_ms_per_krow", {"rows": 0}),
+                                        ("whatif_ms_per_tick", {"ticks": 0})])
+def test_reader_without_the_span_or_rows(name, empty):
     spans = hand_run()["program_spans"]
     del spans["bigroots.whatif"]
-    assert run.load_reader("whatif_ms_per_krow")(hand_run(rows=0)) is None
-    assert run.load_reader("whatif_ms_per_krow")(
-        {"rows": 10, "program_spans": spans}) is None
+    assert run.load_reader(name)(hand_run(**empty)) is None
+    assert run.load_reader(name)(
+        {"rows": 10, "ticks": 1, "program_spans": spans}) is None
 
 
 def test_self_seconds_leaves_outside_children_alone():
@@ -69,12 +82,18 @@ def test_self_seconds_leaves_outside_children_alone():
 
 
 def checkout(tmp_path: Path) -> Path:
-    """A checkout at ``tmp_path`` with the real BENCHMARK.json and bench
-    data and readers, whose traced runs leave their profile under it."""
+    """A checkout at ``tmp_path`` with the real bench data and readers,
+    whose BENCHMARK.json also holds the entries of the held cells
+    (``bench/held/``), and whose traced runs leave their profile under
+    it."""
     (tmp_path / "bench").mkdir(parents=True)
     for d in ("configs", "traffic", "metrics"):
         (tmp_path / "bench" / d).symlink_to(ROOT / "bench" / d)
-    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for held in sorted((ROOT / "bench" / "held").glob("*.json")):
+        for key, items in json.loads(held.read_text()).items():
+            spec[key] += items
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
     return tmp_path
 
 
@@ -94,8 +113,9 @@ def test_program_without_spans_reports_nothing(tmp_path):
     t = place_trace(root)
     assert t["spans"]["bench.tick"]
     assert program_spans.load({"rows": 1_000, "trace": t}, root) == {}
-    for name in NAMES:
-        assert run.load_reader(name, root)({"rows": 1_000, "trace": t}) is None
+    for name in NAMES + TICK_NAMES:
+        assert run.load_reader(name, root)(
+            {"rows": 1_000, "ticks": 3, "trace": t}) is None
 
 
 def test_stale_or_missing_trace_is_not_read(tmp_path):
@@ -118,4 +138,21 @@ def test_traced_rehearsal_reports_the_split(tmp_path):
     # The program's spans nest inside the benchmark's.
     assert sum(m[f"{x}_ms_per_krow"] for x in INGEST) <= m["ingest_ms_per_krow"]
     assert sum(m[f"{x}_ms_per_krow"] for x in SWEEP) <= m["sweep_ms_per_krow"]
+    json.dumps(res)
+
+
+def test_traced_open_loop_rehearsal_reports_the_sweep_split(tmp_path):
+    root = checkout(tmp_path)
+    res = run.run_cell(
+        "hibench_spark_256.steady", 2_200_000_017, 1.5, True, interpret=True,
+        overrides={"config": {"executors": 32, "max_stages": 12,
+                              "stage_tasks": 200, "plan_stages": 400},
+                   "traffic": {"rows_per_s": 200.0}},
+        root=root)
+    assert res["correct"], res["checks"]
+    m = {k: v["value"] for k, v in res["metrics"].items()}
+    assert set(TICK_NAMES) <= set(m)
+    assert all(m[n] > 0 for n in TICK_NAMES)
+    assert sum(m[f"{x}_ms_per_tick"] for x in INGEST) <= m["ingest_ms_per_tick"]
+    assert sum(m[f"{x}_ms_per_tick"] for x in SWEEP) <= m["sweep_ms_per_tick"]
     json.dumps(res)
