@@ -165,15 +165,13 @@ def main(argv: list[str] | None = None) -> int:
     except bench.BenchError as e:
         print(f"program_spans: {e}", file=sys.stderr)
         return 2
-    import reference
     from repro import spans
 
     n_warm = int(traffic["warm_ticks"])
     seen: dict = {}
 
     def watch(svc):
-        """Counters at the window's start and end; the reference's
-        straggler records of every tick."""
+        """Counters at the window's start and end."""
         seen["agg"], tick, calls = svc.agg, svc.diag.tick, [0]
 
         def counted(*a, **kw):
@@ -184,22 +182,11 @@ def main(argv: list[str] | None = None) -> int:
             return out
         svc.diag.tick = counted
 
-    records: list = []
-    ref_step = reference.Reference.step
-
-    def recording(self):
-        fresh, recs = ref_step(self)
-        records.append(recs)
-        return fresh, recs
-    reference.Reference.step = recording
-
     on = bench.run_cell(args.workload, args.seed, args.seconds, True,
                         peaks=peaks, device=device, hooks={"watch": watch})
     end = seen["agg"].counters()
     start = seen.get("start", {})
     counters = {k: end[k] - start.get(k, 0) for k in end}
-    packed_ref = sum(r["stragglers"] for recs in records[n_warm:]
-                     for r in recs)
     # The idle gaps again, labelled by the program's spans as well.
     trace_reduce.SPAN_PREFIX = ("bench.", PREFIX)
     relabelled = trace_reduce.reduce(trace_reduce.find_trace(
@@ -216,7 +203,6 @@ def main(argv: list[str] | None = None) -> int:
                              hooks={"off": off_hook})
     finally:
         spans.enable(None)
-        reference.Reference.step = ref_step
     cost_ns = _span_ns(bench.ROOT / "bench_out" / "span_cost")
     per_tick = entries / max(counters.get("ticks", 0), 1)
     roots = (counters["deltas_ingested"] + counters["ticks"]) / max(
@@ -232,7 +218,7 @@ def main(argv: list[str] | None = None) -> int:
         "ingest_on_over_off": ingest["on"] / ingest["off"] - 1.0,
         "counters": counters,
         "stragglers_check": {"packed": counters["stragglers_packed"],
-                             "reference": packed_ref},
+                             "reference": on["info"]["stragglers_window"]},
         "idle_gaps": relabelled["breakdown"]["idle_gaps"],
         "span_entries_per_tick": per_tick,
         "span_ns": cost_ns,

@@ -8,14 +8,36 @@ workers, the pre-filled windows, warm-up of every shape the cell's ticks
 use, warm ticks), measures ticks of the real entry points for
 ``--seconds`` (``FleetAggregator.ingest`` for each host payload, then
 ``Diagnosis.fleet(agg).tick``), then checks every tick's causes against
-the plain reference (``reference.py``) and prints one JSON line last.
+the configuration's plain reference and prints one JSON line last.
 ``--trace 1`` traces the window with the JAX profiler and reports the
 cell's per-layer metrics instead of its end-to-end ones.
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
 its configuration file, ``bench/traffic/<traffic>.json`` and one reader
-per metric in ``bench/metrics/<metric>.py``.  Off a TPU, or on a device
-kind missing from ``bench/peaks.json``, it exits 2 and prints no result.
+per metric in ``bench/metrics/<metric>.py``.  The configuration names
+the rest:
+
+- ``fleet``: the generator's fleet, ``bench/fleets/<fleet>.py`` (see
+  ``gen.py``);
+- ``schema``: the program's feature schema,
+  ``repro.core.features.get_schema(schema)``, whose columns must be the
+  configuration's ``features``;
+- ``reference`` (``"reference"`` where the key is absent): the plain
+  reference and the comparison, ``bench/<reference>.py``.  It holds
+  ``Reference(cfg, precision=)`` (``"float64"``, or ``"control"`` for the
+  lower-precision control) with ``prefill(blocks)``, ``ingest(blocks)``
+  (one host payload), ``step()`` (one tick: fresh causes and per-window
+  records with ``stragglers``), ``straggler_counts()`` (a tick's change
+  detection alone: the straggler count of each changed window that has
+  any), ``rows_accepted``, ``live_rows`` and ``names``; and
+  ``compare(got, want, records, names)`` with the ``LIMITS`` of the
+  numbers it returns;
+- ``analyzer_options`` and ``aggregator_options``: keyword arguments
+  passed as they are to ``BigRootsAnalyzer`` and ``FleetAggregator``,
+  beside those this file sets, which they may not repeat.
+
+Off a TPU, or on a device kind missing from ``bench/peaks.json``, it
+exits 2 and prints no result.
 """
 import time
 
@@ -23,7 +45,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import faulthandler  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -97,11 +118,21 @@ def cell_metrics(spec: dict, cell: str, trace: bool) -> list[dict]:
 
 def load_reader(name: str, root: Path = ROOT):
     """The ``read(run)`` function of metric ``name``."""
+    import gen
+
     path = root / "bench" / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return gen.load_module(path, f"bench_metric_{name}").read
+
+
+def load_reference(cfg: dict, root: Path):
+    """The plain reference module that configuration ``cfg`` names."""
+    import gen
+
+    name = cfg.get("reference", "reference")
+    path = root / "bench" / f"{name}.py"
+    if not path.is_file():
+        raise BenchError(f"the configuration's reference {path} is missing")
+    return gen.load_module(path, f"bench_reference_{name}")
 
 
 def check_device(chips: int) -> tuple[dict, dict]:
@@ -135,17 +166,32 @@ def cause_record(c) -> dict:
                          else float(a.estimated_recovery_s))}
 
 
+def _options(cfg: dict, key: str, fixed: dict) -> dict:
+    """``fixed`` keyword arguments and the configuration's ``key`` dict,
+    which may add arguments but not repeat one of ``fixed``."""
+    extra = cfg.get(key, {})
+    clash = sorted(fixed.keys() & extra.keys())
+    if clash:
+        raise BenchError(f"the configuration's {key} repeat {clash}, "
+                         "which the benchmark sets")
+    return {**fixed, **extra}
+
+
 class Service:
     """The system under test, built as the configuration states."""
 
     def __init__(self, cfg: dict, *, interpret: bool) -> None:
-        from repro.core import BigRootsAnalyzer, JAX_FEATURES, SPARK_FEATURES
+        from repro.core import BigRootsAnalyzer
         from repro.core.analyzer import BigRootsThresholds
+        from repro.core.features import get_schema
         from repro.serve import Diagnosis
         from repro.serve.fleet import FleetAggregator
         from repro.telemetry.events import StepTelemetry
 
-        schema = {"jax": JAX_FEATURES, "spark": SPARK_FEATURES}[cfg["schema"]]
+        try:
+            schema = get_schema(cfg["schema"])
+        except KeyError as e:
+            raise BenchError(str(e))
         stated = [(n, k) for n, k in cfg["features"].items()]
         actual = [(s.name, s.kind.value) for s in schema]
         if stated != actual:
@@ -156,14 +202,15 @@ class Service:
             quantile=th["quantile"],
             peer_mean=th["peer_mean"],
             time_floor=th["time_floor"], straggler=th["straggler"])
-        self.analyzer = BigRootsAnalyzer(
-            schema, thresholds, backend="pallas", kernel_interpret=interpret,
-            window_exact_quantiles=cfg["lambda_q"] == "exact")
-        self.agg = FleetAggregator(
-            schema, self.analyzer,
-            attribution=bool(cfg["attribution"]),
-            max_stages=int(cfg["max_stages"]),
-            decay_steps=int(cfg["decay_steps"]))
+        self.analyzer = BigRootsAnalyzer(**_options(cfg, "analyzer_options", {
+            "schema": schema, "thresholds": thresholds, "backend": "pallas",
+            "kernel_interpret": interpret,
+            "window_exact_quantiles": cfg["lambda_q"] == "exact"}))
+        self.agg = FleetAggregator(**_options(cfg, "aggregator_options", {
+            "schema": schema, "analyzer": self.analyzer,
+            "attribution": bool(cfg["attribution"]),
+            "max_stages": int(cfg["max_stages"]),
+            "decay_steps": int(cfg["decay_steps"])}))
         self.diag = Diagnosis.fleet(self.agg)
         self.idle = StepTelemetry("diagnosis", schema=schema, wire=True,
                                   boot=1)
@@ -191,14 +238,12 @@ class Service:
                 per_win, np.zeros((1, 1, F)), np.full(W, R)))
 
 
-def launch_shapes(cfg: dict, tr, prefill: list[dict],
+def launch_shapes(reference, cfg: dict, tr, prefill: list[dict],
                   ticks: int | None) -> list[tuple[int, int]]:
     """(windows, padded rows) of every gate launch of ticks 0, 1, ...:
-    the reference's change detection and straggler counts on the
-    generator's rows, for ``ticks`` ticks or, where that is unknown,
-    until ``SETTLED_TICKS`` ticks in a row bring no new shape."""
-    import reference
-
+    the change detection and straggler counts of the ``reference``
+    module on the generator's rows, for ``ticks`` ticks or, where that is
+    unknown, until ``SETTLED_TICKS`` ticks in a row bring no new shape."""
     ref = reference.Reference(cfg)
     ref.prefill(prefill)
     shapes: set[tuple[int, int]] = set()
@@ -253,11 +298,11 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     sys.path.insert(0, str(BENCH))
     import gen
     import kernels
-    import reference
 
     spec, cell, cfg, traffic = load_cell(cell_name, root)
     cfg = dict(cfg, **(overrides or {}).get("config", {}))
     traffic = dict(traffic, **(overrides or {}).get("traffic", {}))
+    reference = load_reference(cfg, root)
     hooks = hooks or {}
     try:
         import repro  # noqa: F401
@@ -274,7 +319,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         jax.monitoring.register_event_duration_secs_listener(_count_trace)
         _traced[1] = 1
 
-    tr = gen.make_traffic(cfg, traffic, seed)
+    tr = gen.make_traffic(cfg, traffic, seed, root)
     n_warm = int(traffic["warm_ticks"])
     open_loop = traffic["arrival"] == "open"
     n_window = math.ceil(seconds / cfg["tick_s"]) if open_loop else None
@@ -283,7 +328,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     # time.
     workers, ahead = (1, n_warm + n_window) if open_loop else (2, 4)
     generator = gen.Generator(cfg, traffic, seed, workers=workers,
-                              ahead=ahead)
+                              ahead=ahead, root=root)
     got: list[list[dict]] = []
     parts = dict(_setup_marks, start=time.perf_counter() - t_start)
     try:
@@ -297,7 +342,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         # Every gate launch shape of the run compiles (or loads from the
         # persistent cache) here, not in the window.
         mark = time.perf_counter()
-        shapes = launch_shapes(cfg, tr, prefill,
+        shapes = launch_shapes(reference, cfg, tr, prefill,
                                n_warm + n_window if open_loop else None)
         parts["shape_lookahead"] = time.perf_counter() - mark
         mark = time.perf_counter()
@@ -414,6 +459,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     info = {"ticks_compared": ticks, "causes_compared": judged["compared"],
             "exempt": judged["exempt"], "examples": judged["examples"],
             "stragglers_last_tick": sum(r["stragglers"] for r in records[-1]),
+            "stragglers_window": sum(r["stragglers"] for recs in
+                                     records[n_warm:] for r in recs),
             "causes_window": sum(len(x) for x in want[n_warm:]),
             "late_ticks": late,
             "lag_growth_ms": lag_growth(lat_ms) if open_loop else None,

@@ -12,8 +12,8 @@ import pytest
 import program_spans
 import run
 import trace_reduce
+from cells import checkout, rehearse
 
-ROOT = Path(run.ROOT)
 TRACE = Path(__file__).parent / "data" / "gates_small.xplane.pb"
 INGEST = ("decode", "merge")
 SWEEP = ("prep", "quantiles", "gates_host", "emit", "whatif")
@@ -81,22 +81,6 @@ def test_self_seconds_leaves_outside_children_alone():
     assert program_spans.self_seconds(spans, "missing", ("c",)) == 0.0
 
 
-def checkout(tmp_path: Path) -> Path:
-    """A checkout at ``tmp_path`` with the real bench data and readers,
-    whose BENCHMARK.json also holds the entries of the held cells
-    (``bench/held/``), and whose traced runs leave their profile under
-    it."""
-    (tmp_path / "bench").mkdir(parents=True)
-    for d in ("configs", "traffic", "metrics"):
-        (tmp_path / "bench" / d).symlink_to(ROOT / "bench" / d)
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    for held in sorted((ROOT / "bench" / "held").glob("*.json")):
-        for key, items in json.loads(held.read_text()).items():
-            spec[key] += items
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
-    return tmp_path
-
-
 def place_trace(root: Path) -> dict:
     """The recorded trace where ``run.py`` leaves a traced run's profile
     under checkout ``root``, and its reduction."""
@@ -125,12 +109,7 @@ def test_stale_or_missing_trace_is_not_read(tmp_path):
 
 
 def test_traced_rehearsal_reports_the_split(tmp_path):
-    root = checkout(tmp_path)
-    res = run.run_cell(
-        "megascale_12k.incident", 2_200_000_017, 1.5, True, interpret=True,
-        overrides={"config": {"hosts": 64, "steps_per_stage": 4,
-                              "max_stages": 3}},
-        root=root)
+    res = rehearse("megascale_12k.incident", tmp_path, trace=True)
     assert res["correct"], res["checks"]
     m = {k: v["value"] for k, v in res["metrics"].items()}
     assert set(NAMES) <= set(m)
@@ -142,13 +121,7 @@ def test_traced_rehearsal_reports_the_split(tmp_path):
 
 
 def test_traced_open_loop_rehearsal_reports_the_sweep_split(tmp_path):
-    root = checkout(tmp_path)
-    res = run.run_cell(
-        "hibench_spark_256.steady", 2_200_000_017, 1.5, True, interpret=True,
-        overrides={"config": {"executors": 32, "max_stages": 12,
-                              "stage_tasks": 200, "plan_stages": 400},
-                   "traffic": {"rows_per_s": 200.0}},
-        root=root)
+    res = rehearse("hibench_spark_256.steady", tmp_path, trace=True)
     assert res["correct"], res["checks"]
     m = {k: v["value"] for k, v in res["metrics"].items()}
     assert set(TICK_NAMES) <= set(m)

@@ -1,56 +1,27 @@
 """CPU rehearsals of whole runs at a tiny size: the Pallas kernel in
 interpret mode, the device check skipped, everything else as on the chip.
 
-Each cell must come out correct; the lower-precision control and each
-fault the cells can have planted in the timed path must come out not
-correct.  A held cell (``bench/held/<cell>.json``: its BENCHMARK.json
-entries, waiting for the chip) is rehearsed with its entries added.
+Each cell, at the size of its ``tiny/<cell>.json``, must come out
+correct; the lower-precision control and each fault the cells can have
+planted in the timed path must come out not correct.  A held cell is
+rehearsed with its entries added.
 """
 import json
 import shutil
-from pathlib import Path
 
 import pytest
 
 import run
+from cells import BY_NAME, ROOT, SEED, TINY, cells, rehearse
 
-TINY = {
-    "megascale_12k.incident": {
-        "config": {"hosts": 64, "steps_per_stage": 4, "max_stages": 3}},
-    "hibench_spark_256.steady": {
-        "config": {"executors": 32, "max_stages": 12,
-                   "stage_tasks": 200, "plan_stages": 400},
-        "traffic": {"rows_per_s": 200.0}},
-}
-SEED = 2_200_000_017
+CELLS = sorted(p.stem for p in TINY.glob("*.json"))
 
 
-def with_entries(root: Path, entries: dict, tmp_path: Path) -> Path:
-    """A checkout at ``tmp_path`` whose BENCHMARK.json also holds
-    ``entries``; its bench/ directories are the real ones."""
-    spec = json.loads((root / "BENCHMARK.json").read_text())
-    for key, items in entries.items():
-        spec[key] += items
-    (tmp_path / "bench").mkdir(parents=True)
-    for d in ("configs", "traffic", "metrics"):
-        (tmp_path / "bench" / d).symlink_to(root / "bench" / d)
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
-    return tmp_path
+def test_every_cell_has_a_rehearsal_size():
+    assert set(cells()) <= set(CELLS)
 
 
-def rehearse(cell, tmp_path, **kw):
-    root = Path(run.ROOT)
-    held = root / "bench" / "held" / f"{cell}.json"
-    if held.exists():
-        root = with_entries(root, json.loads(held.read_text()),
-                            tmp_path / "root")
-    return run.run_cell(cell, kw.pop("seed", SEED), kw.pop("seconds", 1.5),
-                        kw.pop("trace", False), interpret=True,
-                        overrides=TINY[cell], trace_dir=tmp_path / "trace",
-                        root=root, **kw)
-
-
-@pytest.mark.parametrize("cell", sorted(TINY))
+@pytest.mark.parametrize("cell", CELLS)
 def test_cell_runs_correct(cell, tmp_path):
     res = rehearse(cell, tmp_path)
     assert res["correct"], res["checks"]
@@ -62,7 +33,7 @@ def test_cell_runs_correct(cell, tmp_path):
     json.dumps(res)
 
 
-@pytest.mark.parametrize("cell", sorted(TINY))
+@pytest.mark.parametrize("cell", CELLS)
 def test_traced_run_reports_per_layer_metrics(cell, tmp_path):
     res = rehearse(cell, tmp_path, trace=True)
     assert res["correct"], res["checks"]
@@ -76,7 +47,7 @@ def test_traced_run_reports_per_layer_metrics(cell, tmp_path):
                 "gates_kernel_us_per_tick.open"} & set(res["metrics"])
 
 
-@pytest.mark.parametrize("cell", sorted(TINY))
+@pytest.mark.parametrize("cell", CELLS)
 def test_control_is_not_correct(cell, tmp_path):
     res = rehearse(cell, tmp_path, control=True)
     assert not res["correct"]
@@ -112,42 +83,97 @@ def flip_gate_bits(svc):
 
 @pytest.mark.parametrize("fault", [drop_half, stale_tick, flip_gate_bits],
                          ids=lambda f: f.__name__)
-@pytest.mark.parametrize("cell", sorted(TINY))
+@pytest.mark.parametrize("cell", CELLS)
 def test_fault_is_not_correct(cell, fault, tmp_path):
     res = rehearse(cell, tmp_path, hooks={fault.__name__: fault})
     assert not res["correct"], res["checks"]
 
 
-def test_new_cell_needs_only_data(tmp_path):
-    """A configuration, a traffic mix and a per-layer metric added as
-    files plus BENCHMARK.json entries run with no edit to the harness."""
-    root = Path(run.ROOT)
-    for d in ("configs", "traffic", "metrics"):
-        shutil.copytree(root / "bench" / d, tmp_path / "bench" / d)
-    spec = json.loads((root / "BENCHMARK.json").read_text())
-    cfg = json.loads((root / "bench/configs/megascale_12k.json").read_text())
-    cfg.update(name="small_fleet", hosts=32, steps_per_stage=2, max_stages=2)
-    (tmp_path / "bench/configs/small_fleet.json").write_text(json.dumps(cfg))
-    quiet = json.loads((root / "bench/traffic/incident.json").read_text())
-    quiet.update(slow_share=0.012, hot_share=0.0, hot={})
-    (tmp_path / "bench/traffic/quiet.json").write_text(json.dumps(quiet))
-    (tmp_path / "bench/metrics/rows_per_tick.py").write_text(
+#: The planted fault of the copied reference: it drops every cause of the
+#: first tick that has any (its dedup state is kept, so later ticks agree).
+DROP_ONE_TICK = '''
+        self._attribute(fresh)
+        if fresh and not getattr(self, "dropped", False):
+            self.dropped = True
+            fresh = []
+        return fresh, records
+'''
+
+
+@pytest.mark.parametrize("planted", [False, True], ids=["sound", "planted"])
+def test_new_cell_needs_only_data(planted, tmp_path):
+    """A deployment added as new files plus BENCHMARK.json entries runs
+    with no edit to the harness: a configuration that names its own
+    fleet file, reference file and service options, a traffic mix and a
+    per-layer metric.  The named reference is the one consulted: the
+    copy with a fault planted makes the run not correct."""
+    for d in BY_NAME:
+        shutil.copytree(ROOT / "bench" / d, tmp_path / "bench" / d)
+    bench = tmp_path / "bench"
+    (bench / "fleets/drill_fleet.py").write_text(
+        "from pathlib import Path\n\nimport gen\n\n\n"
+        "class Fleet(gen.load_fleet('sync_training', "
+        "Path(__file__).parents[2])):\n"
+        "    \"\"\"The synchronous fleet under another name.\"\"\"\n")
+    ref = (ROOT / "bench/reference.py").read_text()
+    if planted:
+        sound = "\n        self._attribute(fresh)\n        return fresh, records\n"
+        assert ref.count(sound) == 1
+        ref = ref.replace(sound, DROP_ONE_TICK)
+    (bench / "reference_drill.py").write_text(ref)
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cfg = json.loads((ROOT / "bench/configs/megascale_12k.json").read_text())
+    cfg.update(name="small_fleet", hosts=32, steps_per_stage=2, max_stages=2,
+               fleet="drill_fleet", reference="reference_drill",
+               aggregator_options={"reorder_window": 2})
+    (bench / "configs/small_fleet.json").write_text(json.dumps(cfg))
+    drill = json.loads((ROOT / "bench/traffic/incident.json").read_text())
+    drill.update(slow_share=0.05, hot_share=0.01)
+    (bench / "traffic/drill.json").write_text(json.dumps(drill))
+    (bench / "metrics/rows_per_tick.py").write_text(
         "def read(run):\n    return run['rows'] / run['ticks']\n")
     spec["configs"].append({"name": "small_fleet", "source": "https://example.org",
                             "file": "bench/configs/small_fleet.json",
                             "reduced": [], "why": "test"})
-    spec["workloads"].append({"name": "small_fleet.quiet", "config": "small_fleet",
-                              "traffic": "quiet", "chips": 1, "why": "test"})
+    spec["workloads"].append({"name": "small_fleet.drill", "config": "small_fleet",
+                              "traffic": "drill", "chips": 1, "why": "test"})
     for m in spec["end_to_end"]:
         if "workloads" in m and "rows_per_s" == m["name"]:
-            m["workloads"].append("small_fleet.quiet")
+            m["workloads"].append("small_fleet.drill")
     spec["end_to_end"].append({"name": "rows_per_tick", "unit": "rows",
                                "better": "higher", "bound": 0.01,
                                "source": "host_clock",
-                               "workloads": ["small_fleet.quiet"]})
+                               "workloads": ["small_fleet.drill"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
-    res = run.run_cell("small_fleet.quiet", SEED, 1.0, False, interpret=True,
-                       root=tmp_path, trace_dir=tmp_path / "trace")
-    assert res["correct"], res["checks"]
+    built = []
+    res = run.run_cell("small_fleet.drill", SEED, 1.0, False, interpret=True,
+                       root=tmp_path, hooks={"watch": built.append})
+    # The option is not the aggregator's default: the run carried it.
+    assert [svc.agg.reorder_window for svc in built] == [2]
     assert res["metrics"]["rows_per_tick"]["value"] == 32 * 8
     assert {"rows_per_s", "cpu_ms_per_krow", "setup_s"} <= set(res["metrics"])
+    assert res["info"]["causes_compared"] > 0
+    if planted:
+        assert not res["correct"], res["checks"]
+        assert res["checks"]["cause_flips"]["value"] > 0
+    else:
+        assert res["correct"], res["checks"]
+
+
+@pytest.mark.parametrize("key,value", [
+    ("analyzer_options", {"backend": "numpy"}),
+    ("aggregator_options", {"max_stages": 2}),
+])
+def test_options_may_not_repeat_the_harness(key, value):
+    cfg = json.loads((ROOT / "bench/configs/megascale_12k.json").read_text())
+    with pytest.raises(run.BenchError, match=key):
+        run.Service(dict(cfg, **{key: value}), interpret=True)
+
+
+def test_options_reach_the_service():
+    cfg = json.loads((ROOT / "bench/configs/megascale_12k.json").read_text())
+    svc = run.Service(dict(cfg, analyzer_options={"backend_min_rows": 1},
+                           aggregator_options={"reorder_window": 3}),
+                      interpret=True)
+    assert svc.analyzer.backend_min_rows == 1
+    assert svc.agg.reorder_window == 3
